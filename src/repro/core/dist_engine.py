@@ -34,9 +34,9 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..kernels import ops
 from . import padding, refresh_pipeline, sssp
 from .device_engine import (DeviceIndex, RefreshStats,
@@ -517,11 +517,16 @@ class EpochedEngine:
 def serve_sharded(mesh: Mesh, dix: DeviceIndex, s: jax.Array,
                   t: jax.Array, *,
                   batch_axes: Sequence[str] | None = None) -> jax.Array:
-    """Batched queries sharded over ``batch_axes`` (default: all axes)."""
+    """Batched queries sharded over ``batch_axes`` (default: all axes).
+
+    The body is purely local (replicated index, no collectives), so the
+    varying-manual-axes check is off: it would reject serve_step's
+    loops, whose initial carries are fresh unvarying constants while
+    their bodies mix in the sharded query rows."""
     axes = tuple(batch_axes) if batch_axes else tuple(mesh.axis_names)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        shard_map, mesh=mesh, check_vma=False,
         in_specs=(P(), P(axes), P(axes)), out_specs=P(axes))
     def _local(dix_, s_, t_):
         return serve_step(dix_, s_, t_)
@@ -564,10 +569,12 @@ def fw_fragments_sharded(mesh: Mesh, frag_adj: jax.Array,
 def super_apsp_sharded(mesh: Mesh, src: jax.Array, dst: jax.Array,
                        w: jax.Array, n_super: int,
                        axis: str = "data") -> jax.Array:
-    """Offline SUPER APSP: BF sources sharded, edge list replicated."""
+    """Offline SUPER APSP: BF sources sharded, edge list replicated.
+    Purely local like serve_sharded, whose note on the varying-axes
+    check applies to the BF loop's carry here too."""
     srcs = jnp.arange(n_super, dtype=jnp.int32)
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(shard_map, mesh=mesh, check_vma=False,
                        in_specs=(P(), P(), P(), P(axis)),
                        out_specs=P(axis))
     def _local(src_, dst_, w_, sources_):

@@ -46,11 +46,15 @@ from .graph import Graph, SharedGraph
 from .landmarks import HybridCover, hybrid_cover
 from .partition import PartitionResult, partition_bgp
 
-#: fork inherits the parent's read-only pages and needs no module
-#: re-import per worker; spawn is the fallback off Linux.  Cover
-#: workers touch numpy only — never JAX — so forking a process that
-#: has initialized XLA is safe here.
-_MP_START = "fork" if "fork" in mp.get_all_start_methods() else "spawn"
+#: cover workers start from a fork server (spawn off Linux), never by
+#: forking the caller: a caller that has initialized JAX runs XLA and
+#: TPU runtime threads, and JAX warns that forking it can deadlock the
+#: child.  The fork server is a fresh interpreter, so its workers hold
+#: no such state; they re-import this module and attach the shared CSR.
+#: Verified on a TPU v5e host with libtpu initialized: road64k with
+#: build_workers=2 is array-equal to the serial build.
+_MP_START = ("forkserver" if "forkserver" in mp.get_all_start_methods()
+             else "spawn")
 
 
 @dataclasses.dataclass

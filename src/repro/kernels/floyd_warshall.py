@@ -2,10 +2,10 @@
 
 Three entry points:
 
-  * ``fw_batch_pallas``  — grid over a batch of small dense matrices
-    (DISLAND fragments, padded to a common size <= 256); the whole
-    [nf, nf] tile lives in VMEM and a fori_loop runs the classic FW
-    recurrence with a functional carry.
+  * ``fw_batch_pallas``  — grid over a batch of dense matrices
+    (DISLAND fragments, pieces and overlay groups, each padded to a
+    common size); the whole [nf, nf] tile lives in VMEM and each pivot
+    sweeps it in place, row chunk by row chunk.
 
   * ``fw_batch_next_pallas`` — the same, additionally carrying the
     first-hop successor matrix (int32) for exact path reconstruction
@@ -25,22 +25,63 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .minplus import minplus_accum_pallas
 from .ref import fw_next_init
 
 
+#: elements per row chunk of the in-place pivot sweep (16 f32 vregs)
+_CHUNK_ELEMS = 16 * 1024
+#: scoped-VMEM limit of the whole-tile kernels (default 16 MiB).  Each
+#: operand's [n, n] block is held twice, one copy in scoped VMEM: the
+#: witness kernel's four operands take 16 MiB scoped (32 MiB in all) at
+#: n = 1024, road64k's group closures, so the default has no headroom.
+#: At n = 2048 they would take 128 MiB in all, the whole VMEM of a v5e
+#: core, and do not compile.
+_VMEM = pltpu.CompilerParams(vmem_limit_bytes=96 << 20)
+
+
+def _row_chunk(n: int) -> int:
+    """Rows per step of the pivot sweep: the largest multiple of 8 that
+    divides n and keeps a chunk within _CHUNK_ELEMS (the whole tile
+    when n is not a multiple of 8)."""
+    if n % 8:
+        return n
+    return max(c for c in range(8, n + 1, 8)
+               if n % c == 0 and (c == 8 or c * n <= _CHUNK_ELEMS))
+
+
+def _col(blk: jax.Array, k, fill) -> jax.Array:
+    """blk[:, k] as a [rows, 1] column.  Mosaic cannot slice a value's
+    lane axis at a dynamic offset, so this is a masked lane-min: one
+    lane survives the mask, so the result is that entry, bit for bit."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    return jnp.min(jnp.where(lane == k, blk, fill), axis=1, keepdims=True)
+
+
 def _fw_block_kernel(d_ref, o_ref):
-    """In-VMEM Floyd-Warshall on one [nf, nf] tile (leading batch of 1)."""
-    x = d_ref[0]
-    n = x.shape[0]
+    """Floyd-Warshall on one [nf, nf] tile (leading batch of 1), swept in
+    place over the output block.  Pivot k reads row k (a sublane slice)
+    before the sweep, and each row chunk's column k before that chunk is
+    rewritten, so every candidate uses pre-pivot values exactly as the
+    functional recurrence does."""
+    o_ref[...] = d_ref[...]
+    n = o_ref.shape[1]
+    b = _row_chunk(n)
 
-    def body(k, mat):
-        row = jax.lax.dynamic_slice_in_dim(mat, k, 1, axis=0)  # [1, n]
-        col = jax.lax.dynamic_slice_in_dim(mat, k, 1, axis=1)  # [n, 1]
-        return jnp.minimum(mat, col + row)
+    def pivot(k, carry):
+        row = o_ref[0, pl.ds(k, 1), :]                   # [1, n]
 
-    o_ref[0] = jax.lax.fori_loop(0, n, body, x)
+        def chunk(c, carry):
+            rs = pl.ds(pl.multiple_of(c * b, b), b)
+            blk = o_ref[0, rs, :]
+            o_ref[0, rs, :] = jnp.minimum(blk, _col(blk, k, jnp.inf) + row)
+            return carry
+
+        return jax.lax.fori_loop(0, n // b, chunk, carry)
+
+    jax.lax.fori_loop(0, n, pivot, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -57,6 +98,7 @@ def fw_batch_pallas(d: jax.Array, *, interpret: bool = False) -> jax.Array:
         in_specs=[pl.BlockSpec((1, n, n), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((1, n, n), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n, n), d.dtype),
+        compiler_params=_VMEM,
         interpret=interpret,
     )(d)
 
@@ -65,26 +107,33 @@ def _fw_next_block_kernel(d_ref, n_ref, do_ref, no_ref):
     """Witness-carrying FW on one [nf, nf] tile: alongside the distance
     recurrence, carry nxt[i, j] = first hop of a shortest i -> j path
     (int32, -1 = unreachable/diagonal).  Same strict-improvement update
-    in the same pivot order as _fw_block_kernel, so the distance output
-    is bit-identical — path tables can ride along any build without
-    perturbing the distances the rest of the index is tested against."""
-    x = d_ref[0]
-    nx0 = n_ref[0]
-    n = x.shape[0]
+    in the same pivot order and the same in-place sweep as
+    _fw_block_kernel, so the distance output is bit-identical — path
+    tables can ride along any build without perturbing the distances
+    the rest of the index is tested against."""
+    do_ref[...] = d_ref[...]
+    no_ref[...] = n_ref[...]
+    n = do_ref.shape[1]
+    b = _row_chunk(n)
+    no_hop = jnp.iinfo(jnp.int32).max
 
-    def body(k, carry):
-        mat, nxt = carry
-        row = jax.lax.dynamic_slice_in_dim(mat, k, 1, axis=0)  # [1, n]
-        col = jax.lax.dynamic_slice_in_dim(mat, k, 1, axis=1)  # [n, 1]
-        cand = col + row
-        nk = jax.lax.dynamic_slice_in_dim(nxt, k, 1, axis=1)   # nxt[:, k]
-        better = cand < mat
-        return (jnp.where(better, cand, mat),
-                jnp.where(better, jnp.broadcast_to(nk, nxt.shape), nxt))
+    def pivot(k, carry):
+        row = do_ref[0, pl.ds(k, 1), :]                  # [1, n]
 
-    mat, nxt = jax.lax.fori_loop(0, n, body, (x, nx0))
-    do_ref[0] = mat
-    no_ref[0] = nxt
+        def chunk(c, carry):
+            rs = pl.ds(pl.multiple_of(c * b, b), b)
+            blk = do_ref[0, rs, :]
+            nblk = no_ref[0, rs, :]
+            cand = _col(blk, k, jnp.inf) + row
+            better = cand < blk
+            do_ref[0, rs, :] = jnp.where(better, cand, blk)
+            no_ref[0, rs, :] = jnp.where(better, _col(nblk, k, no_hop),
+                                         nblk)
+            return carry
+
+        return jax.lax.fori_loop(0, n // b, chunk, carry)
+
+    jax.lax.fori_loop(0, n, pivot, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -103,6 +152,7 @@ def fw_batch_next_pallas(d: jax.Array, *, interpret: bool = False
                    pl.BlockSpec((1, n, n), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((b, n, n), d.dtype),
                    jax.ShapeDtypeStruct((b, n, n), jnp.int32)],
+        compiler_params=_VMEM,
         interpret=interpret,
     )(d0, nxt0)
 

@@ -7,10 +7,12 @@ node->boundary (A) combined with boundary->boundary SUPER distances (B)
 is exactly a min-plus product (GraphBLAS shortest-distance semiring).
 
 TPU mapping: (min,+) has no MXU form, so this is VPU work; tiles are
-(8,128)-lane aligned and sized so A-tile + B-tile + C-tile + the [bm,
-kc, bn] broadcast scratch stay well inside the ~16 MB VMEM budget.  The
-K grid axis is innermost and sequential on TPU, so the output tile is
-min-accumulated across K invocations (revisiting pattern).
+(8,128)-lane aligned and sized so A-tile + B-tile + C-tile stay well
+inside the 16 MiB default scoped VMEM.  Within a tile the contraction
+runs one k at a time (``minplus_tile``): a column of A broadcast along
+lanes plus a row of B broadcast along sublanes.  The K grid axis is
+innermost and sequential on TPU, so the output tile is min-accumulated
+across K invocations (revisiting pattern).
 """
 from __future__ import annotations
 
@@ -23,7 +25,19 @@ from jax.experimental import pallas as pl
 _NEG_INIT = jnp.inf
 
 
-def _minplus_kernel(a_ref, b_ref, c_ref, *, k_chunk: int):
+def minplus_tile(a: jax.Array, b: jax.Array, acc: jax.Array) -> jax.Array:
+    """min(acc, a (x) b) for one [m, bk] x [bk, n] tile pair, one
+    contraction index at a time.  Column k of ``a`` is a static lane
+    slice and row k of ``b`` a static sublane slice, which Mosaic
+    lowers (it has no dynamic slice of a loaded value).  A min is
+    order-free, so the result is bit-identical to any chunking of the
+    same sums."""
+    for k in range(a.shape[1]):
+        acc = jnp.minimum(acc, a[:, k:k + 1] + b[k:k + 1, :])
+    return acc
+
+
+def _minplus_kernel(a_ref, b_ref, c_ref):
     """One (bm x bn) output tile; min-accumulate over the K grid axis."""
     k = pl.program_id(2)
 
@@ -31,25 +45,13 @@ def _minplus_kernel(a_ref, b_ref, c_ref, *, k_chunk: int):
     def _init():
         c_ref[...] = jnp.full_like(c_ref, _NEG_INIT)
 
-    a = a_ref[...]            # [bm, bk]
-    b = b_ref[...]            # [bk, bn]
-    bk = a.shape[1]
-
-    def body(i, acc):
-        a_c = jax.lax.dynamic_slice_in_dim(a, i * k_chunk, k_chunk, axis=1)
-        b_c = jax.lax.dynamic_slice_in_dim(b, i * k_chunk, k_chunk, axis=0)
-        # [bm, kc, bn] broadcast add, min over kc
-        cand = jnp.min(a_c[:, :, None] + b_c[None, :, :], axis=1)
-        return jnp.minimum(acc, cand)
-
-    acc = jax.lax.fori_loop(0, bk // k_chunk, body, c_ref[...])
-    c_ref[...] = acc
+    c_ref[...] = minplus_tile(a_ref[...], b_ref[...], c_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "k_chunk",
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk",
                                              "interpret"))
 def minplus_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
-                   bn: int = 128, bk: int = 128, k_chunk: int = 8,
+                   bn: int = 128, bk: int = 128,
                    interpret: bool = False) -> jax.Array:
     """Tropical GEMM via Pallas; pads to tile multiples with +inf."""
     m, k = a.shape
@@ -62,7 +64,7 @@ def minplus_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
     b_p = jnp.full((kp, np_), jnp.inf, b.dtype).at[:k, :n].set(b)
     grid = (mp // bm, np_ // bn, kp // bk)
     out = pl.pallas_call(
-        functools.partial(_minplus_kernel, k_chunk=k_chunk),
+        _minplus_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -75,7 +77,7 @@ def minplus_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
     return out[:m, :n]
 
 
-def _minplus_accum_kernel(c_in_ref, a_ref, b_ref, c_ref, *, k_chunk: int):
+def _minplus_accum_kernel(c_in_ref, a_ref, b_ref, c_ref):
     """C = min(C_in, A (x) B) — used by the blocked Floyd-Warshall
     phases 2/3, where the output tile must fold into existing distances."""
     k = pl.program_id(2)
@@ -84,24 +86,13 @@ def _minplus_accum_kernel(c_in_ref, a_ref, b_ref, c_ref, *, k_chunk: int):
     def _init():
         c_ref[...] = c_in_ref[...]
 
-    a = a_ref[...]
-    b = b_ref[...]
-    bk = a.shape[1]
-
-    def body(i, acc):
-        a_c = jax.lax.dynamic_slice_in_dim(a, i * k_chunk, k_chunk, axis=1)
-        b_c = jax.lax.dynamic_slice_in_dim(b, i * k_chunk, k_chunk, axis=0)
-        cand = jnp.min(a_c[:, :, None] + b_c[None, :, :], axis=1)
-        return jnp.minimum(acc, cand)
-
-    c_ref[...] = jax.lax.fori_loop(0, bk // k_chunk, body, c_ref[...])
+    c_ref[...] = minplus_tile(a_ref[...], b_ref[...], c_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "k_chunk",
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk",
                                              "interpret"))
 def minplus_accum_pallas(c: jax.Array, a: jax.Array, b: jax.Array, *,
                          bm: int = 128, bn: int = 128, bk: int = 128,
-                         k_chunk: int = 8,
                          interpret: bool = False) -> jax.Array:
     """min(C, A (x) B) with +inf padding; shapes C[m,n] A[m,k] B[k,n]."""
     m, k = a.shape
@@ -114,7 +105,7 @@ def minplus_accum_pallas(c: jax.Array, a: jax.Array, b: jax.Array, *,
     c_p = jnp.full((mp, np_), jnp.inf, c.dtype).at[:m, :n].set(c)
     grid = (mp // bm, np_ // bn, kp // bk)
     out = pl.pallas_call(
-        functools.partial(_minplus_accum_kernel, k_chunk=k_chunk),
+        _minplus_accum_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),

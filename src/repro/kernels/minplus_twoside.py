@@ -25,10 +25,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .minplus import minplus_tile
+
 _LANES = 128
 
 
-def _twoside_kernel(rows_ref, d_ref, rowt_ref, out_ref, *, k_chunk: int):
+def _twoside_kernel(rows_ref, d_ref, rowt_ref, out_ref):
     """Min-accumulate one (q, y, x) tile triple into lane partials."""
     yi = pl.program_id(1)
     xi = pl.program_id(2)
@@ -38,22 +40,10 @@ def _twoside_kernel(rows_ref, d_ref, rowt_ref, out_ref, *, k_chunk: int):
         out_ref[...] = jnp.full_like(out_ref, jnp.inf)
 
     rows = rows_ref[...]          # [bq, bk1]
-    d = d_ref[...]                # [bk1, bk2]
     rowt = rowt_ref[...]          # [bq, bk2]
-    bk1 = rows.shape[1]
     bq, bk2 = rowt.shape
-
-    def body(i, acc):
-        r_c = jax.lax.dynamic_slice_in_dim(rows, i * k_chunk, k_chunk,
-                                           axis=1)
-        d_c = jax.lax.dynamic_slice_in_dim(d, i * k_chunk, k_chunk,
-                                           axis=0)
-        # [bq, kc, bk2] broadcast add, min over the x chunk
-        cand = jnp.min(r_c[:, :, None] + d_c[None, :, :], axis=1)
-        return jnp.minimum(acc, cand)
-
-    tmp = jax.lax.fori_loop(0, bk1 // k_chunk, body,
-                            jnp.full((bq, bk2), jnp.inf, rows.dtype))
+    tmp = minplus_tile(rows, d_ref[...],
+                       jnp.full((bq, bk2), jnp.inf, rows.dtype))
     tmp = tmp + rowt              # [bq, bk2]
     # fold the y tile down to its 128 lanes; cross-lane min is done by
     # the caller so every store here stays (8, 128)-aligned
@@ -62,11 +52,10 @@ def _twoside_kernel(rows_ref, d_ref, rowt_ref, out_ref, *, k_chunk: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bk1", "bk2",
-                                             "k_chunk", "interpret"))
+                                             "interpret"))
 def minplus_twoside_pallas(rows: jax.Array, d: jax.Array,
                            rowt: jax.Array, *, bq: int = 128,
                            bk1: int = 128, bk2: int = 128,
-                           k_chunk: int = 8,
                            interpret: bool = False) -> jax.Array:
     """out[q] = min_{x,y} rows[q,x] + d[x,y] + rowt[q,y].
 
@@ -78,7 +67,7 @@ def minplus_twoside_pallas(rows: jax.Array, d: jax.Array,
     qb, k2b = rowt.shape
     assert k1 == k1b and k2 == k2b and q == qb, (rows.shape, d.shape,
                                                 rowt.shape)
-    assert bk2 % _LANES == 0 and bk1 % k_chunk == 0, (bk1, bk2, k_chunk)
+    assert bk2 % _LANES == 0, bk2
     qp = -(-q // bq) * bq
     k1p = -(-k1 // bk1) * bk1
     k2p = -(-k2 // bk2) * bk2
@@ -87,7 +76,7 @@ def minplus_twoside_pallas(rows: jax.Array, d: jax.Array,
     rowt_p = jnp.full((qp, k2p), jnp.inf, rowt.dtype).at[:q, :k2].set(rowt)
     grid = (qp // bq, k2p // bk2, k1p // bk1)
     part = pl.pallas_call(
-        functools.partial(_twoside_kernel, k_chunk=k_chunk),
+        _twoside_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, bk1), lambda qi, yi, xi: (qi, xi)),
@@ -102,7 +91,7 @@ def minplus_twoside_pallas(rows: jax.Array, d: jax.Array,
 
 
 def _twoside_argmin_kernel(rows_ref, d_ref, rowt_ref, out_ref, wit_ref,
-                           *, k_chunk: int, k2_stride: int):
+                           *, k2_stride: int):
     """Witness-carrying variant of _twoside_kernel: alongside the lane
     partial minima, carry the winning (x, y) pair packed as
     x * k2_stride + y (global padded coordinates, int32).  Ties resolve
@@ -120,27 +109,15 @@ def _twoside_argmin_kernel(rows_ref, d_ref, rowt_ref, out_ref, wit_ref,
     rowt = rowt_ref[...]          # [bq, bk2]
     bk1 = rows.shape[1]
     bq, bk2 = rowt.shape
-
-    def body(i, carry):
-        acc, accx = carry
-        r_c = jax.lax.dynamic_slice_in_dim(rows, i * k_chunk, k_chunk,
-                                           axis=1)
-        d_c = jax.lax.dynamic_slice_in_dim(d, i * k_chunk, k_chunk,
-                                           axis=0)
-        cube = r_c[:, :, None] + d_c[None, :, :]   # [bq, kc, bk2]
-        cand = jnp.min(cube, axis=1)
-        hit = cube == cand[:, None, :]
-        loc = jnp.min(jnp.where(
-            hit,
-            jax.lax.broadcasted_iota(jnp.int32, cube.shape, 1),
-            jnp.int32(bk1)), axis=1)
+    # one x at a time, as in minplus_tile; the strict < keeps the
+    # smallest x among equal sums
+    acc = jnp.full((bq, bk2), jnp.inf, rows.dtype)
+    accx = jnp.full((bq, bk2), -1, jnp.int32)
+    for x in range(bk1):
+        cand = rows[:, x:x + 1] + d[x:x + 1, :]
         better = cand < acc
-        return (jnp.where(better, cand, acc),
-                jnp.where(better, i * k_chunk + loc, accx))
-
-    acc0 = jnp.full((bq, bk2), jnp.inf, rows.dtype)
-    accx0 = jnp.full((bq, bk2), -1, jnp.int32)
-    acc, accx = jax.lax.fori_loop(0, bk1 // k_chunk, body, (acc0, accx0))
+        acc = jnp.where(better, cand, acc)
+        accx = jnp.where(better, x, accx)
     tmp = acc + rowt              # [bq, bk2]
     # pack the global witness per (q, y) cell, then fold y to 128 lanes
     # keeping value/witness aligned (min-of-where instead of argmin so
@@ -162,11 +139,10 @@ def _twoside_argmin_kernel(rows_ref, d_ref, rowt_ref, out_ref, wit_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bk1", "bk2",
-                                             "k_chunk", "interpret"))
+                                             "interpret"))
 def minplus_twoside_argmin_pallas(rows: jax.Array, d: jax.Array,
                                   rowt: jax.Array, *, bq: int = 128,
                                   bk1: int = 128, bk2: int = 128,
-                                  k_chunk: int = 8,
                                   interpret: bool = False
                                   ) -> tuple[jax.Array, jax.Array,
                                              jax.Array]:
@@ -180,7 +156,7 @@ def minplus_twoside_argmin_pallas(rows: jax.Array, d: jax.Array,
     qb, k2b = rowt.shape
     assert k1 == k1b and k2 == k2b and q == qb, (rows.shape, d.shape,
                                                 rowt.shape)
-    assert bk2 % _LANES == 0 and bk1 % k_chunk == 0, (bk1, bk2, k_chunk)
+    assert bk2 % _LANES == 0, bk2
     qp = -(-q // bq) * bq
     k1p = -(-k1 // bk1) * bk1
     k2p = -(-k2 // bk2) * bk2
@@ -189,8 +165,7 @@ def minplus_twoside_argmin_pallas(rows: jax.Array, d: jax.Array,
     rowt_p = jnp.full((qp, k2p), jnp.inf, rowt.dtype).at[:q, :k2].set(rowt)
     grid = (qp // bq, k2p // bk2, k1p // bk1)
     part, pwit = pl.pallas_call(
-        functools.partial(_twoside_argmin_kernel, k_chunk=k_chunk,
-                          k2_stride=k2p),
+        functools.partial(_twoside_argmin_kernel, k2_stride=k2p),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, bk1), lambda qi, yi, xi: (qi, xi)),

@@ -9,19 +9,20 @@ state.  Axis semantics:
 from __future__ import annotations
 
 import jax
-
-from ..compat import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return make_host_mesh(shape, axes)
 
 
 def make_host_mesh(shape=None, axes=("data", "model")):
-    """Small mesh over whatever devices exist (tests / smoke runs)."""
+    """Mesh over whatever devices exist (tests / smoke runs), every axis
+    Auto; ``shape`` defaults to all devices on the first axis."""
     n = len(jax.devices())
     if shape is None:
         shape = (n, 1) if len(axes) == 2 else (n,)
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

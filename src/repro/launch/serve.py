@@ -33,6 +33,8 @@ validated against the host oracle *of the epoch that served it*.
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -215,6 +217,25 @@ def _validate_sample(g, s, t, got, n_check: int, *,
     return bad
 
 
+def _validate_paths(g, s, t, dist, paths, n_check: int) -> int:
+    """Path sample vs host Dijkstra on ``g``: every path must be edge-
+    valid with weight == served distance == Dijkstra, exactly (None
+    where unreachable).  Returns (and prints) the mismatch count."""
+    bad = 0
+    n_check = min(n_check, len(s))
+    for i in range(n_check):
+        want = dijkstra.pair(g, int(s[i]), int(t[i]))
+        if np.isinf(want):
+            bad += paths[i] is not None
+            continue
+        w = path_weight(g, paths[i])          # raises on a broken hop
+        if not (w == float(dist[i]) == want):
+            bad += 1
+    print(f"path validation: {bad} mismatches of {n_check} "
+          "(edge-valid, weight == serve == Dijkstra, exact)")
+    return bad
+
+
 def _emit(args, records: list, label: str, *, prev_filter=None,
           prev_key: str | None = None) -> None:
     """Append perf records to --json (when enabled), printing the most
@@ -334,18 +355,8 @@ def _paths_loop(engine: EpochedEngine, args) -> list:
           f"{summ['median_s'] * 1e3:.2f}ms -> {per_p * 1e6:.2f}us/path "
           f"({pps:,.0f} paths/s, mean {np.mean(hops):.1f} hops)")
     s, t, dist, paths = last
-    bad = 0
-    for i in range(min(args.validate, len(s))):
-        want = dijkstra.pair(engine.g, int(s[i]), int(t[i]))
-        if np.isinf(want):
-            bad += paths[i] is not None
-            continue
-        w = path_weight(engine.g, paths[i])   # raises on a broken hop
-        if not (w == float(dist[i]) == want):
-            bad += 1
-    print(f"path validation: {bad} mismatches of {args.validate} "
-          "(edge-valid, weight == serve == Dijkstra, exact)")
-    assert bad == 0
+    assert _validate_paths(engine.g, s, t, dist, paths,
+                           args.validate) == 0
     return [{
         "section": "serve_paths",
         "graph": _label(args),
@@ -541,7 +552,24 @@ def _live_loop(engine: EpochedEngine, args) -> list:
 # ---------------------------------------------------------------------------
 # driver
 # ---------------------------------------------------------------------------
-def main() -> None:
+#: the checkout root (src/repro/launch/serve.py -> three levels up)
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache``: the directory is part of the cache key,
+    so it must not move between runs."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(CHECKOUT / ".jax_cache"))
+
+
+def build_parser() -> argparse.ArgumentParser:
+    """The serve CLI's flags; cross-flag checks live in ``main``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=4000)
     ap.add_argument("--graph", default=None,
@@ -669,7 +697,12 @@ def main() -> None:
                           "trace JSON here at exit (build, refresh, "
                           "and per-request serve spans; load in "
                           "chrome://tracing)")
-    args = ap.parse_args()
+    return ap
+
+
+def resolve_graph(args) -> None:
+    """Apply ``--graph``'s preset (node count, label, hierarchy) and
+    normalize the numeric knobs that arrive as strings."""
     preset = None
     if args.graph:
         from ..data.roads import road_preset
@@ -683,6 +716,12 @@ def main() -> None:
         args.hierarchy_levels = int(args.hierarchy_levels)
     if args.resident_mb != "auto":
         args.resident_mb = float(args.resident_mb)
+
+
+def main(argv=None) -> None:
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    resolve_graph(args)
     mode = "sharded" if args.sharded else args.mode
     if args.expect_hierarchy and mode != "planner":
         # the guard lives in _build_engine (planner setup); accepting
@@ -709,6 +748,7 @@ def main() -> None:
     if (args.metrics_out or args.metrics_port) and not args.live:
         ap.error("--metrics-out/--metrics-port require --live (the "
                  "metrics registry lives on the serving runtime)")
+    use_compile_cache()
     if args.trace_out:
         # enable before the build so the build/refresh stage spans
         # land in the same trace as the serve lifecycle events

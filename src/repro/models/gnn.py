@@ -26,8 +26,8 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from ..compat import shard_map
 from .common import Shardings
 
 

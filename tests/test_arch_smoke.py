@@ -112,9 +112,9 @@ def test_lm_smoke_prefill_decode(arch_id):
 def test_all_cells_build_on_tiny_mesh():
     """Every (arch x shape) cell must assemble (structs + shardings) on
     a 1x1 mesh without touching device memory."""
-    from repro.compat import make_mesh
     from repro.launch.cells import build_cell
-    mesh = make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh((1, 1))
     for arch_id in list_archs():
         for cell in get_arch(arch_id).shapes:
             b = build_cell(arch_id, cell.name, mesh)

@@ -20,7 +20,8 @@ def _run(body: str) -> None:
         sys.path.insert(0, %r)
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_host_mesh
     """ % os.path.join(ROOT, "src")) + textwrap.dedent(body)
     res = subprocess.run([sys.executable, "-c", prog],
                          capture_output=True, text=True, timeout=420)
@@ -34,7 +35,7 @@ def test_sharded_serve_matches_host_engine():
         from repro.core.device_engine import build_device_index
         from repro.core.dist_engine import serve_sharded
         from repro.core.engine import DislandEngine
-        mesh = make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh((4, 2))
         g = road_like(900, seed=31)
         ix = build_index(g)
         dix = build_device_index(ix)
@@ -53,11 +54,26 @@ def test_sharded_serve_matches_host_engine():
     """)
 
 
+def test_chip_smoke_four_chips_matches_planner():
+    """chip_smoke.py --four-chips' phase: serve_sharded over every
+    device equals the one-device planner, and Dijkstra, bit for bit."""
+    _run("""
+        import importlib.util
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", os.path.join(%r, "chip_smoke.py"))
+        chip_smoke = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(chip_smoke)
+        chip_smoke.four_chips(["--nodes", "2000", "--batch-size", "128",
+                               "--validate", "16", "--json", ""])
+        print("ok")
+    """ % ROOT)
+
+
 def test_compressed_psum_approximates_mean():
     _run("""
         import functools
         from repro.optim import compressed_psum
-        mesh = make_mesh((8,), ("d",))
+        mesh = make_host_mesh((8,), ("d",))
         x = jnp.asarray(np.random.default_rng(0).normal(
             size=(8, 64)).astype(np.float32))
         @functools.partial(shard_map, mesh=mesh,
@@ -79,7 +95,7 @@ def test_gnn_sharded_matches_dense():
         from repro.models import gnn
         from repro.models.common import Shardings
         P_ = 8
-        mesh = make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh((4, 2))
         rng = np.random.default_rng(3)
         n, d = 64, 8          # 8 nodes per shard
         npp = n // P_
@@ -121,7 +137,7 @@ def test_dimenet_sharded_matches_dense_local_triplets():
         from repro.models import gnn
         from repro.models.common import Shardings
         P_ = 8
-        mesh = make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh((4, 2))
         rng = np.random.default_rng(5)
         n, d = 64, 6
         npp = n // P_
@@ -178,7 +194,7 @@ def test_lm_sharded_loss_matches_single_device():
         import dataclasses
         from repro.models import transformer
         from repro.models.common import Shardings
-        mesh = make_mesh((2, 4), ("data", "model"))
+        mesh = make_host_mesh((2, 4))
         cfg = transformer.LMConfig(
             name="t", n_layers=2, d_model=32, n_heads=4, n_kv_heads=2,
             d_ff=64, vocab=64, dtype=jnp.float32, attn_chunk=8,
