@@ -27,12 +27,14 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
 
-#: the serve CLI flags of the road64k deployment the smoke drives
+#: the serve CLI flags of the road64k deployment the smoke drives; the
+#: live window may take its time to drain (it checks answers, not
+#: latency)
 ROAD64K = ["--graph", "road64k", "--expect-hierarchy", "3",
            "--hub-budget", "2048", "--batch-size", "1024",
            "--validate", "64", "--update-frac", "0.02",
            "--mix", "zipf", "--rate", "500", "--live-seconds", "4",
-           "--json", ""]
+           "--live-wait-timeout", "300", "--json", ""]
 #: offline batches after the planner warmup
 BATCHES = 3
 #: paths served, unwound and checked: unwinding is host work that
@@ -158,6 +160,7 @@ def _refresh(engine, args, rng) -> None:
 def _live(engine, args) -> None:
     from repro.launch import serve
 
+    args.live = True
     t0 = time.perf_counter()
     rec = serve._live_loop(engine, args)[0]
     _log(f"live: {rec['n_requests']} requests in "
@@ -181,14 +184,11 @@ def one_chip(serve_argv) -> None:
     rng = np.random.default_rng(args.seed + 1)
     for name, phase in (("offline", lambda: _offline(engine, args, rng)),
                         ("paths", lambda: _paths(engine, rng)),
-                        ("refresh", lambda: _refresh(engine, args, rng))):
+                        ("refresh", lambda: _refresh(engine, args, rng)),
+                        ("live", lambda: _live(engine, args))):
         t0 = time.perf_counter()
         phase()
         _log(f"phase {name}: {time.perf_counter() - t0:.3f}s")
-    args.live = True
-    t0 = time.perf_counter()
-    _live(engine, args)
-    _log(f"phase live: {time.perf_counter() - t0:.3f}s")
 
 
 def four_chips(serve_argv) -> None:

@@ -308,7 +308,7 @@ def make_build_plan(ix: DislandIndex) -> BuildPlan:
     sup_fi = np.concatenate(fi_parts)
     sup_pu = np.concatenate(pu_parts)
     sup_pv = np.concatenate(pv_parts)
-    order = np.argsort(ek)
+    order = np.argsort(ek, kind="stable")
 
     # ---- piece registry + per-node lookups ------------------------------
     piece_gid = -np.ones(n, dtype=np.int32)

@@ -98,7 +98,7 @@ def ref_graph(g: Graph) -> Graph:
                     heapq.heappush(pq, (nd, y))
         return np.inf
 
-    order = np.argsort(-g.edge_w)
+    order = np.argsort(-g.edge_w, kind="stable")
     alive = np.ones(g.m, dtype=bool)
     for i in order:
         u, v, w = int(g.edge_u[i]), int(g.edge_v[i]), float(g.edge_w[i])

@@ -161,7 +161,10 @@ def _initial_partition(g: Graph, node_w: np.ndarray, gamma: int,
     """
     labels = -np.ones(g.n, dtype=np.int64)
     frag = 0
-    order = np.argsort(np.diff(g.indptr))  # grow from low-degree periphery
+    # grow from the low-degree periphery; degrees tie everywhere, and an
+    # unstable sort orders ties by the CPU's SIMD path, so the partition
+    # (and the whole index) would differ between hosts
+    order = np.argsort(np.diff(g.indptr), kind="stable")
     for seed in order:
         if labels[seed] >= 0:
             continue

@@ -51,8 +51,10 @@ from .partition import PartitionResult, partition_bgp
 #: TPU runtime threads, and JAX warns that forking it can deadlock the
 #: child.  The fork server is a fresh interpreter, so its workers hold
 #: no such state; they re-import this module and attach the shared CSR.
-#: Verified on a TPU v5e host with libtpu initialized: road64k with
-#: build_workers=2 is array-equal to the serial build.
+#: As with spawn, a script that asks for ``build_workers > 1`` needs an
+#: ``if __name__ == "__main__":`` guard.  Verified on a TPU v5e host
+#: with libtpu initialized: road64k with build_workers=2 is
+#: array-equal to the serial build.
 _MP_START = ("forkserver" if "forkserver" in mp.get_all_start_methods()
              else "spawn")
 

@@ -221,3 +221,42 @@ def test_failed_cover_surfaces_original_exception(workers):
     with pytest.raises(_InjectedCoverFailure, match="injected"):
         build_index(g, build_workers=workers, cover_fn=_boom_cover)
     assert _shm_names() <= before        # no leaked shared-memory block
+
+
+# ---------------------------------------------------------------------------
+# host independence
+# ---------------------------------------------------------------------------
+_DIGEST = """
+import hashlib, sys
+sys.path.insert(0, %r)
+from repro.core.graph import road_like
+from repro.core.supergraph import build_index
+ix = build_index(road_like(4000, seed=5))
+h = hashlib.sha1()
+for a in (ix.partition.labels, ix.frag_of, ix.shrink_ids):
+    h.update(a.tobytes())
+print(h.hexdigest())
+"""
+
+
+def test_build_is_independent_of_simd_dispatch():
+    """numpy sorts ties differently on each SIMD path (AVX-512 or not),
+    so an unstable sort in the build makes the index depend on the
+    host's CPU.  The build with numpy's AVX-512 kernels turned off must
+    equal the build with whatever this CPU offers."""
+    import subprocess
+    import sys
+
+    from numpy._core._multiarray_umath import __cpu_features__
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    prog = _DIGEST % src
+    off = " ".join(f for f, on in __cpu_features__.items()
+                   if on and f.startswith("AVX512"))
+    runs = [subprocess.run([sys.executable, "-c", prog], env=env,
+                           capture_output=True, text=True, timeout=300)
+            for env in (dict(os.environ),
+                        dict(os.environ, NPY_DISABLE_CPU_FEATURES=off))]
+    for r in runs:
+        assert r.returncode == 0, r.stderr
+    assert runs[0].stdout == runs[1].stdout

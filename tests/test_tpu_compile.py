@@ -4,11 +4,12 @@ that is described, not attached, at road64k widths.
 Interpret mode (every other kernel test) cannot see what the TPU's
 compiler refuses: slices Mosaic cannot lower, or more scoped VMEM than
 a kernel may use.  These compiles can, with no chip.  The widths are
-those of the road64k preset's build (``data/roads.py``, seed 0): 130
-fragment tiles at maxf = 496, DRA pieces at caps 8 and 32, group
-closures at m2 = 1024 (6 groups on level 1), a top closure over
-S_top = 1711 boundary nodes (d2 and the label rows are S_top + 1 = 1712
-wide), and serve batches up to 1024 queries.
+those of the road64k preset's build (``data/roads.py``, seed 0), which
+is the same on every host: 128 fragment tiles at maxf = 496, DRA
+pieces at caps 8 and 32, group closures at m2 = 1024 (6 groups on
+level 1, 4 on level 2), a top closure over S_top = 1867 boundary nodes
+(d2 and the label rows are S_top + 1 = 1868 wide), and serve batches
+up to 1024 queries.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and under several
@@ -23,7 +24,7 @@ from repro.kernels import floyd_warshall as fw
 from repro.kernels import label_merge as lm
 from repro.kernels import minplus_twoside as ts
 
-S_TOP = 1711
+S_TOP = 1867
 BATCH = 1024
 
 
@@ -61,7 +62,7 @@ def _compile(fn, sharding, *shapes):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("batch,n", [(130, 496),     # fragment tiles
+@pytest.mark.parametrize("batch,n", [(128, 496),     # fragment tiles
                                      (6211, 8),      # DRA pieces
                                      (75, 32),
                                      (6, 1024)])     # group closures
