@@ -1561,9 +1561,10 @@ def _hier_leg(dix: DeviceIndex, li: int, row_s, grp_s, pos_s,
                        axis=1)
         return jnp.minimum(acc, cand)
 
-    tmp = jax.lax.fori_loop(0, mbs // c, body,
-                            jnp.full((q, mbt), INF, row_s.dtype))
-    return jnp.min(tmp + row_t, axis=1)
+    with jax.named_scope(f"hier_leg_l{li}"):
+        tmp = jax.lax.fori_loop(0, mbs // c, body,
+                                jnp.full((q, mbt), INF, row_s.dtype))
+        return jnp.min(tmp + row_t, axis=1)
 
 
 def _lift_compact(dix: DeviceIndex, li: int, row, grp, pos):
@@ -1586,8 +1587,9 @@ def _lift_compact(dix: DeviceIndex, li: int, row, grp, pos):
         return jnp.minimum(acc,
                            jnp.min(r_c[:, :, None] + l2_c, axis=1))
 
-    return jax.lax.fori_loop(0, mb // c, body,
-                             jnp.full((q, mbn), INF, row.dtype))
+    with jax.named_scope(f"lift_l{li}"):
+        return jax.lax.fori_loop(0, mb // c, body,
+                                 jnp.full((q, mbn), INF, row.dtype))
 
 
 def _lift_src_of(dix: DeviceIndex, li: int, row, ids, grp, pos, wc):
@@ -1694,13 +1696,14 @@ def _combine_mid_h(dix: DeviceIndex, row_s, bs, row_t, bt, *,
         ids_s = dix.bnd2_sid[li][grp_s[:, 0]]
         ids_t = dix.bnd2_sid[li][grp_t[:, 0]]
         row_s, row_t = new_s, new_t
-    if ops.use_pallas(force):
-        vb = ops.minplus_twoside(_scatter_top(dix, row_s, ids_s),
-                                 dix.d2,
-                                 _scatter_top(dix, row_t, ids_t),
-                                 force=force)
-    else:
-        vb = _top_mid_gather(dix, row_s, ids_s, row_t, ids_t)
+    with jax.named_scope("top_combine"):
+        if ops.use_pallas(force):
+            vb = ops.minplus_twoside(_scatter_top(dix, row_s, ids_s),
+                                     dix.d2,
+                                     _scatter_top(dix, row_t, ids_t),
+                                     force=force)
+        else:
+            vb = _top_mid_gather(dix, row_s, ids_s, row_t, ids_t)
     return jnp.minimum(va, vb)
 
 
@@ -1818,26 +1821,30 @@ def _combine_mid(dix: DeviceIndex, row_s, bs, row_t, bt, *, force=None):
     """
     if len(dix.sf_of):
         return _combine_mid_h(dix, row_s, bs, row_t, bt, force=force)
-    if ops.use_pallas(force):
-        s1 = dix.d_super.shape[0]
-        q = row_s.shape[0]
-        qi = jnp.arange(q, dtype=jnp.int32)[:, None]
-        rs = jnp.full((q, s1), INF, row_s.dtype).at[qi, bs].min(row_s)
-        rt = jnp.full((q, s1), INF, row_t.dtype).at[qi, bt].min(row_t)
-        return ops.minplus_twoside(rs, dix.d_super, rt, force=force)
-    q, mb = row_s.shape
-    c = min(8, mb)                       # mb is padded to a multiple of 8
+    with jax.named_scope("top_combine"):
+        if ops.use_pallas(force):
+            s1 = dix.d_super.shape[0]
+            q = row_s.shape[0]
+            qi = jnp.arange(q, dtype=jnp.int32)[:, None]
+            rs = jnp.full((q, s1), INF,
+                          row_s.dtype).at[qi, bs].min(row_s)
+            rt = jnp.full((q, s1), INF,
+                          row_t.dtype).at[qi, bt].min(row_t)
+            return ops.minplus_twoside(rs, dix.d_super, rt, force=force)
+        q, mb = row_s.shape
+        c = min(8, mb)                   # mb is padded to a multiple of 8
 
-    def body(i, acc):
-        r_c = jax.lax.dynamic_slice_in_dim(row_s, i * c, c, axis=1)
-        b_c = jax.lax.dynamic_slice_in_dim(bs, i * c, c, axis=1)
-        blk = dix.d_super[b_c[:, :, None], bt[:, None, :]]  # [q, c, mb]
-        cand = jnp.min(r_c[:, :, None] + blk, axis=1)
-        return jnp.minimum(acc, cand)
+        def body(i, acc):
+            r_c = jax.lax.dynamic_slice_in_dim(row_s, i * c, c, axis=1)
+            b_c = jax.lax.dynamic_slice_in_dim(bs, i * c, c, axis=1)
+            blk = dix.d_super[b_c[:, :, None],
+                              bt[:, None, :]]          # [q, c, mb]
+            cand = jnp.min(r_c[:, :, None] + blk, axis=1)
+            return jnp.minimum(acc, cand)
 
-    tmp = jax.lax.fori_loop(0, mb // c, body,
-                            jnp.full((q, mb), INF, row_s.dtype))
-    return jnp.min(tmp + row_t, axis=1)
+        tmp = jax.lax.fori_loop(0, mb // c, body,
+                                jnp.full((q, mb), INF, row_s.dtype))
+        return jnp.min(tmp + row_t, axis=1)
 
 
 def _combine_mid_w(dix: DeviceIndex, row_s, bs, row_t, bt, *,
@@ -1937,8 +1944,9 @@ def serve_cross(dix: DeviceIndex, s: jax.Array, t: jax.Array, *,
     mid = _combine_mid(dix, row_s, dix.bnd_super[fs], row_t,
                        dix.bnd_super[ft], force=force)
     if with_local:
-        mid = jnp.minimum(mid, jnp.where(fs == ft,
-                                         dix.frag_apsp[fs, ps, pt], INF))
+        with jax.named_scope("local_fold"):
+            mid = jnp.minimum(mid, jnp.where(
+                fs == ft, dix.frag_apsp[fs, ps, pt], INF))
     d = ds + mid + dt
     return jnp.where((fs >= 0) & (ft >= 0), d, INF)
 
@@ -2000,8 +2008,9 @@ def _lift_res(dix: DeviceIndex, row, pos, ridx, cols=None):
 
     width = stp1 if cols is None else cols.shape[1]
     body = body_full if cols is None else body_cols
-    return jax.lax.fori_loop(0, mb // c, body,
-                             jnp.full((q, width), INF, row.dtype))
+    with jax.named_scope("lift_res"):
+        return jax.lax.fori_loop(0, mb // c, body,
+                                 jnp.full((q, width), INF, row.dtype))
 
 
 def serve_cross_res(dix: DeviceIndex, s: jax.Array, t: jax.Array, *,

@@ -38,6 +38,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels import ops
+from ..obs import trace
 from . import padding, refresh_pipeline, sssp
 from .device_engine import (DeviceIndex, RefreshStats,
                             build_device_index_with_plan, refresh_index,
@@ -58,6 +59,15 @@ _pad_pow2 = padding.pad_pow2
 pad_pow2 = padding.pad_pow2
 
 
+def _program(name: str, fn, **kw):
+    """``jax.jit`` of ``fn`` with ``kw`` bound, named ``name``: its XLA
+    module is ``jit_<name>`` in a profile (a bare partial's would be
+    ``jit__unknown``).  The name changes nothing in the compiled code."""
+    p = functools.partial(fn, **kw)
+    p.__name__ = p.__qualname__ = name
+    return jax.jit(p)
+
+
 class QueryPlanner:
     """Bucket a query batch by case and dispatch per-case programs.
 
@@ -76,23 +86,23 @@ class QueryPlanner:
     def __init__(self, dix: DeviceIndex, *, force=None,
                  paths: bool = False):
         self._fns = {
-            "same_dra": jax.jit(serve_same_dra),
-            "same_frag": jax.jit(functools.partial(
-                serve_cross, with_local=True, force=force)),
-            "cross_frag": jax.jit(functools.partial(
-                serve_cross, with_local=False, force=force)),
+            "same_dra": _program("planner_same_dra", serve_same_dra),
+            "same_frag": _program("planner_same_frag", serve_cross,
+                                  with_local=True, force=force),
+            "cross_frag": _program("planner_cross_frag", serve_cross,
+                                   with_local=False, force=force),
             # resident fast path: both endpoints in pre-lifted hot
             # super-fragments of *different* top-level groups, so the
             # whole query is one fused twoside against the top closure
-            "cross_res": jax.jit(functools.partial(
-                serve_cross_res, force=force)),
+            "cross_res": _program("planner_cross_res", serve_cross_res,
+                                  force=force),
         }
         # hub-label hot tier (DESIGN.md §15): NOT a planner case — the
         # serving runtime gates pairs with hub_mask and dispatches them
         # through query_hub, above/instead of the planner.  Jitting is
         # free until called, so the program exists on every index and
         # warmup() only compiles it when the epoch carries real labels.
-        self._hub_fn = jax.jit(functools.partial(serve_hub, force=force))
+        self._hub_fn = _program("planner_hub", serve_hub, force=force)
         # witness-returning (return_witness mode) sub-programs; jit
         # wrappers are free until called, so these always exist and
         # ``paths`` only decides whether warmup() compiles them.
@@ -101,13 +111,13 @@ class QueryPlanner:
         # them may disagree with the unwinder's exact re-find — witness
         # queries keep the exact pipeline (distances are equal anyway)
         self._wfns = {
-            "same_dra": jax.jit(serve_same_dra_w),
-            "same_frag": jax.jit(functools.partial(
-                serve_cross_w, with_local=True, force=force)),
-            "cross_frag": jax.jit(functools.partial(
-                serve_cross_w, with_local=False, force=force)),
-            "cross_res": jax.jit(functools.partial(
-                serve_cross_w, with_local=False, force=force)),
+            "same_dra": _program("planner_same_dra_w", serve_same_dra_w),
+            "same_frag": _program("planner_same_frag_w", serve_cross_w,
+                                  with_local=True, force=force),
+            "cross_frag": _program("planner_cross_frag_w", serve_cross_w,
+                                   with_local=False, force=force),
+            "cross_res": _program("planner_cross_res_w", serve_cross_w,
+                                  with_local=False, force=force),
         }
         self.paths = paths
         self.last_counts: dict = {}
@@ -260,12 +270,17 @@ class QueryPlanner:
         if s.size == 0:
             return np.zeros(s.shape, np.float32)
         m = _pad_pow2(s.size)
-        sp = np.zeros(m, np.int32)
-        tp = np.zeros(m, np.int32)
-        sp[:s.size] = s
-        tp[:t.size] = t
-        res = self._hub_fn(dix, jnp.asarray(sp), jnp.asarray(tp))
-        return np.asarray(res)[:s.size]
+        with trace.span("planner.query", n=int(s.size)), \
+                trace.span("planner.bucket", case="hub", n=int(s.size),
+                           padded=m):
+            sp = np.zeros(m, np.int32)
+            tp = np.zeros(m, np.int32)
+            sp[:s.size] = s
+            tp[:t.size] = t
+            res = self._hub_fn(dix, jnp.asarray(sp), jnp.asarray(tp))
+            with trace.span("planner.device_wait"):
+                res = np.asarray(res)
+            return res[:s.size]
 
     def _dispatch(self, fns, s, t, outs, dix=None) -> None:
         """Shared bucket/pad/dispatch loop: partition (s, t), pad each
@@ -275,23 +290,35 @@ class QueryPlanner:
         ``dix`` pins the epoch; defaulting to the planner's current
         pointer, read ONCE so a concurrent set_index between bucket
         dispatches cannot split one batch across two epochs.
+
+        Spans (DESIGN.md §16): ``planner.query`` over the call,
+        ``planner.plan`` over the bucketing, one ``planner.bucket``
+        per non-empty case (tags ``case``, ``n``, ``padded``) and
+        inside it ``planner.device_wait`` over the blocking copy back;
+        the planner's host time is the query span less its waits.
         """
         dix = self.dix if dix is None else dix
-        plan = self.plan(s, t, dix)
-        self.last_counts = {c: int(ix.size) for c, ix in plan.items()}
-        for case, idx in plan.items():
-            if idx.size == 0:
-                continue
-            m = _pad_pow2(idx.size)
-            sp = np.zeros(m, np.int32)
-            tp = np.zeros(m, np.int32)
-            sp[:idx.size] = s[idx]
-            tp[:idx.size] = t[idx]
-            res = fns[case](dix, jnp.asarray(sp), jnp.asarray(tp))
-            if len(outs) == 1:
-                res = (res,)
-            for out, r in zip(outs, res):
-                out[idx] = np.asarray(r)[:idx.size]
+        with trace.span("planner.query", n=int(s.size)):
+            with trace.span("planner.plan"):
+                plan = self.plan(s, t, dix)
+            self.last_counts = {c: int(ix.size) for c, ix in plan.items()}
+            for case, idx in plan.items():
+                if idx.size == 0:
+                    continue
+                m = _pad_pow2(idx.size)
+                with trace.span("planner.bucket", case=case,
+                                n=int(idx.size), padded=m):
+                    sp = np.zeros(m, np.int32)
+                    tp = np.zeros(m, np.int32)
+                    sp[:idx.size] = s[idx]
+                    tp[:idx.size] = t[idx]
+                    res = fns[case](dix, jnp.asarray(sp), jnp.asarray(tp))
+                    if len(outs) == 1:
+                        res = (res,)
+                    with trace.span("planner.device_wait"):
+                        res = [np.asarray(r) for r in res]
+                    for out, r in zip(outs, res):
+                        out[idx] = r[:idx.size]
 
     def __call__(self, s, t) -> np.ndarray:
         return self.query(s, t)

@@ -12,15 +12,18 @@ Three pieces, each independently usable:
   no-op singleton), a ``timed()`` helper that keeps the historical
   ``timings``-dict contract while emitting spans, and raw ``event()``
   emission for intervals measured elsewhere (per-request lifecycle).
-  Build stages, ``refresh_index`` stages, hierarchy closures, and the
-  serve flush all trace through the module-level default tracer.
+  Build stages, ``refresh_index`` stages, hierarchy closures, the
+  serve flush and the planner all trace through the module-level
+  default tracer; while a JAX profiler session records, every span is
+  also mirrored into it as a ``TraceAnnotation``.
 * ``export`` — Chrome-trace JSONL writer/loader (opens in
   chrome://tracing), atomic periodic metrics snapshots + Prometheus
   text exposition (``serve.py --metrics-out/--metrics-port``), and
   the worst-N ``SlowQueryLog``.
 
-The overhead contract: with tracing disabled (the default), call
-sites cost one attribute read; with everything on, live road4000
+The overhead contract: with tracing disabled (the default) and no
+profiler recording, call sites cost one attribute read and one
+``TraceAnnotation.is_enabled()`` call; with everything on, live road4000
 serving stays within the measured <2% qps budget (``BENCH_serve.json``
 section ``obs_overhead``, enforced by the A-B in ``tests/test_obs.py``).
 """

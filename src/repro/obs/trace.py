@@ -5,10 +5,10 @@ One ``Tracer`` holds a bounded in-memory buffer of Chrome-trace
 built so that EVERY production call site stays hot-path-safe when
 tracing is off:
 
-* ``span(name, **tags)`` — context manager.  Disabled, it returns a
-  shared no-op singleton whose ``__enter__``/``__exit__`` are empty
-  methods: no allocation, no clock read, no tag dict materialized
-  beyond the call itself.
+* ``span(name, **tags)`` — context manager.  Disabled (tracer off and
+  no profiler recording), it returns a shared no-op singleton whose
+  ``__enter__``/``__exit__`` are empty methods: no allocation, no
+  clock read, no tag dict materialized beyond the call itself.
 * ``timed(name, out, key, **tags)`` — like ``span`` but ALWAYS times
   (one ``perf_counter`` pair) and writes the elapsed seconds into
   ``out[key]``.  This is the migration target for the hand-rolled
@@ -21,10 +21,21 @@ tracing is off:
   from ``Request.t_sched``/``t_done``).  Disabled, it's one attribute
   check.
 
-Spans nest per-thread: each thread's open-span depth is tracked so
-tests can assert nesting/ordering invariants, and events carry the
-thread id so chrome://tracing lays concurrent flusher/refresh/export
-activity out on separate rows.
+A span is live while its tracer is enabled OR a JAX profiler session
+is recording (``jax.profiler.start_trace`` .. ``stop_trace``).  While
+the profiler records, every live ``span``/``timed`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name with its tags as
+metadata, so it lands on the profiler's host plane, on the device
+trace's clock; it still goes into the buffer as well.  ``event()``
+measured its interval after the fact and cannot reach the profiler:
+it stays buffer-only, and only while the tracer is enabled.
+
+Spans nest per-thread: each buffered event carries an ``id``, the
+``parent`` id of the span that enclosed it on the same thread, and
+its ``depth``, so readers can rebuild the tree and tests can assert
+nesting/ordering invariants; events carry the thread id so
+chrome://tracing lays concurrent flusher/refresh/export activity out
+on separate rows.
 
 A module-level default tracer (``get_tracer()``) is what the library
 call sites use; ``serve.py --trace-out`` enables it and drains the
@@ -32,8 +43,14 @@ buffer into a Chrome-trace JSON at exit.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+#: True while a JAX profiler session records (one native call)
+_profiling = TraceAnnotation.is_enabled
 
 
 class _NullSpan:
@@ -51,49 +68,68 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _annotate(name: str, tags: dict):
+    """An entered ``TraceAnnotation`` mirroring a span into the
+    recording profiler session, or None when none records."""
+    if not _profiling():
+        return None
+    ann = TraceAnnotation(name, **tags)
+    ann.__enter__()
+    return ann
+
+
 class _Span:
     """One live span: records wall-clock bounds on exit and appends a
-    Chrome "X" event to its tracer's buffer."""
+    Chrome "X" event to its tracer's buffer (and mirrors itself into
+    the profiler while one records)."""
 
-    __slots__ = ("_tracer", "name", "tags", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "tags", "_t0", "_depth", "_id",
+                 "_parent", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, tags: dict):
         self._tracer = tracer
         self.name = name
         self.tags = tags
 
+    def _open(self) -> None:
+        self._depth, self._id, self._parent = self._tracer._push()
+        self._ann = _annotate(self.name, self.tags)
+
+    def _close(self, t1: float) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tracer._pop()
+        self._tracer._emit(self.name, self._t0, t1, self.tags,
+                           self._depth, self._id, self._parent)
+
     def __enter__(self):
-        self._depth = self._tracer._enter_depth()
+        self._open()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        self._tracer._exit_depth()
-        self._tracer._emit(self.name, self._t0, t1, self.tags,
-                           self._depth)
+        self._close(time.perf_counter())
         return False
 
 
-class _Timed:
+class _Timed(_Span):
     """Always-on timer that doubles as a span: elapsed seconds land in
-    ``out[key]`` unconditionally, and in the trace buffer when the
-    tracer is enabled.  ``.elapsed`` is readable after exit."""
+    ``out[key]`` unconditionally, and it traces like a span when the
+    tracer is enabled or the profiler records.  ``.elapsed`` is
+    readable after exit."""
 
-    __slots__ = ("_tracer", "name", "_out", "_key", "tags", "_t0",
-                 "_depth", "elapsed")
+    __slots__ = ("_out", "_key", "_live", "elapsed")
 
     def __init__(self, tracer, name, out, key, tags):
-        self._tracer = tracer
-        self.name = name
+        super().__init__(tracer, name, tags)
         self._out = out
         self._key = key
-        self.tags = tags
         self.elapsed = 0.0
 
     def __enter__(self):
-        self._depth = self._tracer._enter_depth() \
-            if self._tracer.enabled else 0
+        self._live = self._tracer.enabled or _profiling()
+        if self._live:
+            self._open()
         self._t0 = time.perf_counter()
         return self
 
@@ -102,10 +138,8 @@ class _Timed:
         self.elapsed = t1 - self._t0
         if self._out is not None:
             self._out[self._key] = self.elapsed
-        if self._tracer.enabled:
-            self._tracer._exit_depth()
-            self._tracer._emit(self.name, self._t0, t1, self.tags,
-                               self._depth)
+        if self._live:
+            self._close(t1)
         return False
 
 
@@ -124,25 +158,35 @@ class Tracer:
         self._events: list[dict] = []
         self.dropped = 0
         self._local = threading.local()
+        self._ids = itertools.count(1)
         # one fixed origin so every event's ts is a positive offset
         self._origin = time.perf_counter()
 
-    # -- depth tracking (per-thread nesting, for tests/ordering) ------
-    def _enter_depth(self) -> int:
-        d = getattr(self._local, "depth", 0)
-        self._local.depth = d + 1
-        return d
+    # -- nesting (per-thread stack of open span ids) -----------------
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
-    def _exit_depth(self) -> None:
-        self._local.depth = getattr(self._local, "depth", 1) - 1
+    def _push(self) -> tuple[int, int, int | None]:
+        """Open a span on this thread -> (depth, id, parent id)."""
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        sid = next(self._ids)
+        stack.append(sid)
+        return len(stack) - 1, sid, parent
+
+    def _pop(self) -> None:
+        self._stack().pop()
 
     @property
     def depth(self) -> int:
         """Current thread's open-span depth."""
-        return getattr(self._local, "depth", 0)
+        return len(self._stack())
 
     # -- emission -----------------------------------------------------
-    def _emit(self, name, t0, t1, tags, depth) -> None:
+    def _emit(self, name, t0, t1, tags, depth, sid, parent) -> None:
         ev = {
             "name": name,
             "ph": "X",
@@ -152,6 +196,9 @@ class Tracer:
             "tid": threading.get_ident() % 100_000,
             "args": dict(tags) if tags else {},
         }
+        ev["args"]["id"] = sid
+        if parent is not None:
+            ev["args"]["parent"] = parent
         if depth:
             ev["args"]["depth"] = depth
         with self._lock:
@@ -167,22 +214,24 @@ class Tracer:
         return self
 
     def span(self, name: str, **tags):
-        """Context manager; the no-op singleton when disabled."""
-        if not self.enabled:
+        """Context manager; the no-op singleton when the tracer is
+        disabled and no profiler records."""
+        if not self.enabled and not _profiling():
             return _NULL_SPAN
         return _Span(self, name, tags)
 
     def timed(self, name: str, out: dict | None, key: str, **tags):
         """Context manager that always times into ``out[key]`` and
-        additionally traces when enabled."""
+        additionally traces when enabled or the profiler records."""
         return _Timed(self, name, out, key, tags)
 
     def event(self, name: str, t0: float, t1: float, **tags) -> None:
         """Emit a completed interval measured by the caller (both
-        bounds on the ``perf_counter`` clock)."""
+        bounds on the ``perf_counter`` clock).  Buffer-only: the
+        profiler cannot take an interval after the fact."""
         if not self.enabled:
             return
-        self._emit(name, t0, t1, tags, 0)
+        self._emit(name, t0, t1, tags, 0, next(self._ids), None)
 
     def events(self) -> list[dict]:
         """Copy of the buffered events (chronological emit order)."""
@@ -204,7 +253,7 @@ class Tracer:
 
 # Module-level default: library call sites trace through this; it
 # stays disabled (no-op spans, skipped events) unless a front end —
-# serve.py --trace-out, a test — enables it.
+# serve.py --trace-out, a test — enables it, or a profiler records.
 _DEFAULT = Tracer()
 
 
@@ -214,7 +263,7 @@ def get_tracer() -> Tracer:
 
 def span(name: str, **tags):
     """Span on the default tracer (the common call-site spelling)."""
-    if not _DEFAULT.enabled:
+    if not _DEFAULT.enabled and not _profiling():
         return _NULL_SPAN
     return _Span(_DEFAULT, name, tags)
 
@@ -225,5 +274,5 @@ def timed(name: str, out: dict | None, key: str, **tags):
 
 
 def event(name: str, t0: float, t1: float, **tags) -> None:
-    if _DEFAULT.enabled:
-        _DEFAULT._emit(name, t0, t1, tags, 0)
+    """Post-hoc event on the default tracer (buffer-only)."""
+    _DEFAULT.event(name, t0, t1, **tags)

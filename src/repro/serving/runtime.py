@@ -180,22 +180,20 @@ class ServingRuntime:
             label_s = planner_s = 0.0
             lag = stale.lag_batches if stale is not None else 0
             if hub.any():
-                t0 = time.perf_counter()
-                out[hub] = planner.query_hub(s[hub], t[hub], dix=dix)
-                t1 = time.perf_counter()
-                label_s = t1 - t0
                 label_n = int(hub.sum())
-                trace.event("serve.tier.label", t0, t1, n=label_n,
-                            epoch=epoch, staleness=lag)
+                with trace.timed("serve.tier.label", None, None,
+                                 n=label_n, epoch=epoch,
+                                 staleness=lag) as tm:
+                    out[hub] = planner.query_hub(s[hub], t[hub], dix=dix)
+                label_s = tm.elapsed
             rest = ~hub
             if rest.any():
-                t0 = time.perf_counter()
-                out[rest] = planner.query(s[rest], t[rest], dix=dix)
-                t1 = time.perf_counter()
-                planner_s = t1 - t0
                 planner_n = int(rest.sum())
-                trace.event("serve.tier.planner", t0, t1, n=planner_n,
-                            epoch=epoch, staleness=lag)
+                with trace.timed("serve.tier.planner", None, None,
+                                 n=planner_n, epoch=epoch,
+                                 staleness=lag) as tm:
+                    out[rest] = planner.query(s[rest], t[rest], dix=dix)
+                planner_s = tm.elapsed
             for req, d, h in zip(misses, out, hub):
                 req.dist = float(d)
                 req.epoch = epoch

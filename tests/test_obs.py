@@ -412,11 +412,22 @@ def test_scripted_serve_trace_export(engine, tmp_path):
     assert {e["args"]["epoch"] for e in reqs} == {e0, e0 + 1}
     assert by_name.get("serve.cache_lookup")
     assert by_name.get("serve.tier.planner")
-    # tier-resolution spans nest inside their flush span
+    # tier-resolution spans nest inside their flush span: in time, and
+    # as spans (parent ids) one level below it
     f0 = flushes[0]
     t0 = by_name["serve.tier.planner"][0]
     assert f0["ts"] <= t0["ts"] + 1e-3
     assert t0["ts"] + t0["dur"] <= f0["ts"] + f0["dur"] + 1e-3
+    flush_ids = {f["args"]["id"] for f in flushes}
+    tiers = by_name["serve.tier.planner"] + by_name.get("serve.tier.label",
+                                                        [])
+    for t in tiers:
+        assert t["args"]["parent"] in flush_ids
+        assert t["args"]["depth"] == f0["args"].get("depth", 0) + 1
+    # ... and the planner's own spans one level below the tiers'
+    assert by_name.get("planner.query")
+    assert {q["args"]["parent"] for q in by_name["planner.query"]} \
+        <= {t["args"]["id"] for t in tiers}
 
     # golden write -> load roundtrip (chrome://tracing-compatible)
     path = str(tmp_path / "serve_trace.json")
