@@ -1541,13 +1541,45 @@ def _overlay_size(dix: DeviceIndex) -> int:
 
 
 def _hier_leg(dix: DeviceIndex, li: int, row_s, grp_s, pos_s,
-              row_t, grp_t, pos_t):
+              row_t, grp_t, pos_t, *, force=None):
     """Same-group leg at grouping level ``li``: min over slot pairs
     (i, j) in the SAME level-li group of
-    row_s[i] + sf_closure[li][g, pos_i, pos_j] + row_t[j], chunked
-    over the s-axis so the gathered block stays [q, 8, width]."""
+    row_s[i] + sf_closure[li][g, pos_i, pos_j] + row_t[j].
+
+    Accelerator: all valid slots of one side lie in one group (groups
+    nest; _lift_compact relies on it too), so the leg is a two-sided
+    (min,+) contraction against that group's closure block.  Each side
+    is scattered into the group's m2 coordinates (its sentinel slots,
+    whose group differs from slot 0's, are dropped), and each real
+    group g runs the fused minplus_twoside over the rows of the
+    queries whose two sides both lie in g; a query whose sides lie in
+    different groups gets +inf, as the gather's mask gives it.  That
+    is ng * q * m2^2 pairs per level, streamed through VMEM, in place
+    of a per-query scalar gather.  CPU/ref: the gather, chunked over
+    the s-axis so the gathered block stays [q, 8, width].  Both are
+    exact: every sum is an integer-valued f32 below 2**24."""
     q, mbs = row_s.shape
     mbt = row_t.shape[1]
+    if ops.use_pallas(force):
+        ng, m2 = dix.sf_closure[li].shape[:2]
+        ng -= 1                                  # last group: sentinel
+        qi = jnp.arange(q, dtype=jnp.int32)[:, None]
+
+        def dense(row, grp, pos):
+            own = jnp.where(grp == grp[:, :1], pos, m2)  # m2: dropped
+            return jnp.full((q, m2), INF, row.dtype).at[qi, own].min(
+                row, mode="drop")
+
+        with jax.named_scope(f"hier_leg_l{li}"):
+            rs, rt = dense(row_s, grp_s, pos_s), dense(row_t, grp_t, pos_t)
+            gs, gt = grp_s[:, 0], grp_t[:, 0]
+            va = jnp.full((q,), INF, row_s.dtype)
+            for g in range(ng):
+                both = ((gs == g) & (gt == g))[:, None]
+                va = jnp.minimum(va, ops.minplus_twoside(
+                    jnp.where(both, rs, INF), dix.sf_closure[li][g], rt,
+                    force=force))
+            return va
     c = min(8, mbs)                    # widths are padded to mult of 8
 
     def body(i, acc):
@@ -1670,11 +1702,14 @@ def _combine_mid_h(dix: DeviceIndex, row_s, bs, row_t, bt, *,
     level-l group (its resident closure answers exactly — the va
     legs), or the route crosses every level's boundary and the TOP
     closure answers against both rows lifted level by level (the vb
-    leg).  On an accelerator the vb leg scatters both rows dense and
-    runs the SAME fused minplus_twoside kernel as the dense path; on
-    CPU it stays compact and gathers only each side's own top-group
-    boundary columns of d2 (_top_mid_gather — bit-identical, the
-    scattered row is +inf everywhere else).  The lift state stays
+    leg).  On an accelerator every leg is a fused minplus_twoside
+    contraction: each va leg scatters both rows into their group's
+    closure coordinates and contracts once per real group (_hier_leg),
+    and the vb leg scatters both rows dense against d2, as the dense
+    path does.  On CPU the va legs gather their closure entries, and
+    the vb leg stays compact and gathers only each side's own
+    top-group boundary columns of d2 (_top_mid_gather — bit-identical,
+    the scattered row is +inf everywhere else).  The lift state stays
     compact ([q, width] + ids) until the top; one grouping level
     reproduces the two-level combine bit-for-bit (min re-association
     is exact in f32).
@@ -1687,7 +1722,7 @@ def _combine_mid_h(dix: DeviceIndex, row_s, bs, row_t, bt, *,
         grp_s, pos_s = dix.sf_of[li][ids_s], dix.pos_in_sf[li][ids_s]
         grp_t, pos_t = dix.sf_of[li][ids_t], dix.pos_in_sf[li][ids_t]
         va = jnp.minimum(va, _hier_leg(dix, li, row_s, grp_s, pos_s,
-                                       row_t, grp_t, pos_t))
+                                       row_t, grp_t, pos_t, force=force))
         new_s = _lift_compact(dix, li, row_s, grp_s, pos_s)
         new_t = _lift_compact(dix, li, row_t, grp_t, pos_t)
         # slot 0 is valid-first by construction, so its group IS the

@@ -68,6 +68,46 @@ def _program(name: str, fn, **kw):
     return jax.jit(p)
 
 
+def _frag_groups(dix: DeviceIndex) -> tuple:
+    """Per grouping level, fragment -> its level-li group (-1 for a
+    fragment with no boundary), read off the index's own arrays: a
+    fragment's level-0 group is that of its first boundary slot, and a
+    level-li group's group one level up that of the first id of its
+    bnd2_sid row (slots are valid-first; groups nest)."""
+    ids = np.asarray(dix.bnd_super)[:, 0]
+    out = []
+    for li in range(len(dix.sf_of)):
+        sf_of = np.asarray(dix.sf_of[li])
+        grp = sf_of[ids]                       # sentinel id -> sentinel group
+        out.append(np.where(grp == sf_of[-1], -1, grp))
+        ids = np.asarray(dix.bnd2_sid[li])[grp, 0]
+    return tuple(out)
+
+
+def _host_maps(dix: DeviceIndex) -> tuple:
+    """(dix, agent_of, frag_of, res_frag, topgrp, hub_agent, groups):
+    the host copies the planner buckets, gates and tags with."""
+    return (dix, np.asarray(dix.agent_of), np.asarray(dix.frag_of),
+            getattr(dix, "host_res_frag", None),
+            getattr(dix, "host_topgrp_frag", None),
+            getattr(dix, "host_hub_agent", None), _frag_groups(dix))
+
+
+def _same_group_counts(s, t, maps) -> dict:
+    """{"same_l<li>": number of (s, t) pairs whose two fragments share
+    a real level-li group} — the rows of each level's same-group
+    contraction that can do useful work."""
+    _, agent_of, frag_of, *_, groups = maps
+    if not groups:                                 # dense epoch
+        return {}
+    fs, ft = frag_of[agent_of[s]], frag_of[agent_of[t]]
+    valid = (fs >= 0) & (ft >= 0)
+    fs, ft = np.where(valid, fs, 0), np.where(valid, ft, 0)
+    return {f"same_l{li}": int(np.count_nonzero(
+                valid & (g[fs] == g[ft]) & (g[fs] >= 0)))
+            for li, g in enumerate(groups)}
+
+
 class QueryPlanner:
     """Bucket a query batch by case and dispatch per-case programs.
 
@@ -82,6 +122,9 @@ class QueryPlanner:
     """
 
     CASES = ("same_dra", "same_frag", "cross_frag", "cross_res")
+    #: the cases whose distance programs run the per-level same-group
+    #: legs (_combine_mid_h); their planner.bucket spans carry same_l<li>
+    LEG_CASES = ("same_frag", "cross_frag")
 
     def __init__(self, dix: DeviceIndex, *, force=None,
                  paths: bool = False):
@@ -133,11 +176,17 @@ class QueryPlanner:
         # bucket with ITS epoch's maps even if this publish lands
         # mid-flush (weight-only refreshes share these arrays across
         # epochs, but the epoch-pin contract must not depend on that)
-        self._maps = (dix, np.asarray(dix.agent_of),
-                      np.asarray(dix.frag_of),
-                      getattr(dix, "host_res_frag", None),
-                      getattr(dix, "host_topgrp_frag", None),
-                      getattr(dix, "host_hub_agent", None))
+        self._maps = _host_maps(dix)
+
+    def _maps_of(self, dix: DeviceIndex | None) -> tuple:
+        """The host maps of ``dix`` (default: current epoch): the
+        cached tuple, or — pinned to an epoch that is no longer
+        current, only reachable when a publish lands between the pin
+        and this dispatch — derived afresh from that index."""
+        cached = self._maps          # single atomic read of the tuple
+        if dix is None or cached[0] is dix:
+            return cached
+        return _host_maps(dix)
 
     @staticmethod
     def bucket_sizes(batch_size: int) -> list[int]:
@@ -181,18 +230,7 @@ class QueryPlanner:
              dix: DeviceIndex | None = None) -> dict:
         """-> {case: index array} partition of the batch, bucketed by
         ``dix``'s own membership maps (default: current epoch)."""
-        cached = self._maps          # single atomic read of the tuple
-        if dix is None or cached[0] is dix:
-            agent_of, frag_of = cached[1], cached[2]
-            res_frag, topgrp = cached[3], cached[4]
-        else:
-            # pinned to an epoch that is no longer current: derive the
-            # maps from that index (cold path — only reachable when a
-            # publish lands between the pin and this dispatch)
-            agent_of = np.asarray(dix.agent_of)
-            frag_of = np.asarray(dix.frag_of)
-            res_frag = getattr(dix, "host_res_frag", None)
-            topgrp = getattr(dix, "host_topgrp_frag", None)
+        _, agent_of, frag_of, res_frag, topgrp, _, _ = self._maps_of(dix)
         us, ut = agent_of[s], agent_of[t]
         fs, ft = frag_of[us], frag_of[ut]
         case1 = us == ut
@@ -229,16 +267,8 @@ class QueryPlanner:
         boundary the labels enumerate).  Everything else falls through
         to the planner.  Reads the same atomically-published map tuple
         as plan(), so a pinned dispatch gates with ITS epoch's labels."""
-        cached = self._maps
-        if dix is None or cached[0] is dix:
-            dix_, agent_of, frag_of = cached[0], cached[1], cached[2]
-            topgrp, hub_agent = cached[4], cached[5]
-        else:
-            dix_ = dix
-            agent_of = np.asarray(dix.agent_of)
-            frag_of = np.asarray(dix.frag_of)
-            topgrp = getattr(dix, "host_topgrp_frag", None)
-            hub_agent = getattr(dix, "host_hub_agent", None)
+        dix_, agent_of, frag_of, _, topgrp, hub_agent, _ = \
+            self._maps_of(dix)
         s = np.asarray(s, np.int64)
         t = np.asarray(t, np.int64)
         if hub_agent is None:
@@ -306,8 +336,12 @@ class QueryPlanner:
                 if idx.size == 0:
                     continue
                 m = _pad_pow2(idx.size)
+                legs = (_same_group_counts(s[idx], t[idx],
+                                           self._maps_of(dix))
+                        if case in self.LEG_CASES and trace.recording()
+                        else {})
                 with trace.span("planner.bucket", case=case,
-                                n=int(idx.size), padded=m):
+                                n=int(idx.size), padded=m, **legs):
                     sp = np.zeros(m, np.int32)
                     tp = np.zeros(m, np.int32)
                     sp[:idx.size] = s[idx]

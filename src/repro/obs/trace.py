@@ -261,6 +261,12 @@ def get_tracer() -> Tracer:
     return _DEFAULT
 
 
+def recording() -> bool:
+    """True while spans on the default tracer are live (it is enabled,
+    or a profiler records): the gate for a tag that costs host work."""
+    return _DEFAULT.enabled or _profiling()
+
+
 def span(name: str, **tags):
     """Span on the default tracer (the common call-site spelling)."""
     if not _DEFAULT.enabled and not _profiling():

@@ -24,13 +24,15 @@ import numpy as np
 import pytest
 
 from repro.core import dijkstra, hierarchy
-from repro.core.device_engine import (build_device_index,
+from repro.core.device_engine import (_hier_leg, _lift_compact,
+                                      build_device_index,
                                       build_device_index_with_plan,
                                       index_fields_equal,
                                       overlay_slot_table,
                                       refresh_index,
                                       resolve_hierarchy_levels,
-                                      serve_one_to_all, serve_step)
+                                      serve_cross, serve_one_to_all,
+                                      serve_step)
 from repro.core.dist_engine import EpochedEngine
 from repro.core.graph import road_like, traffic_updates
 from repro.core.paths import path_weight
@@ -143,6 +145,142 @@ def test_hier_pallas_layout_parity(built):
     np.testing.assert_array_equal(
         np.asarray(serve_step(dix2, s, t)),
         np.asarray(serve_step(dix2, s, t, force="pallas")))
+
+
+@pytest.fixture(scope="module")
+def deep():
+    """Two grouping levels (hierarchy_levels=3), each with at least two
+    real groups."""
+    g = road_like(2500, seed=7)
+    dix = build_device_index(build_index(g), hierarchy_levels=3)
+    assert len(dix.sf_of) == 2
+    assert all(c.shape[0] - 1 >= 2 for c in dix.sf_closure)
+    return g, dix
+
+
+def _side_groups(dix, frag):
+    """Per grouping level, the group of each fragment's side (-1: none),
+    walked up exactly as _combine_mid_h lifts a side."""
+    ids = np.asarray(dix.bnd_super)[frag]
+    out = []
+    for li in range(len(dix.sf_of)):
+        sf_of = np.asarray(dix.sf_of[li])
+        grp = sf_of[ids]
+        out.append(np.where(grp[:, 0] == sf_of[-1], -1, grp[:, 0]))
+        ids = np.asarray(dix.bnd2_sid[li])[grp[:, 0]]
+    return out
+
+
+@pytest.mark.parametrize("which", ["built", "deep"])
+def test_each_side_lies_in_one_group(which, request):
+    """The invariant the same-group contraction rests on: at every
+    grouping level, all valid slots of a fragment's side lie in ONE
+    group, and slot 0 is valid whenever any slot is."""
+    fix = request.getfixturevalue(which)
+    dix = fix[3][0] if which == "built" else fix[1]
+    ids = np.asarray(dix.bnd_super)
+    for li in range(len(dix.sf_of)):
+        sf_of = np.asarray(dix.sf_of[li])
+        valid = ids != sf_of.shape[0] - 1
+        grp = sf_of[ids]
+        assert (valid[:, 0] | ~valid.any(axis=1)).all(), li
+        for f in range(ids.shape[0]):
+            assert np.unique(grp[f][valid[f]]).size <= 1, (li, f)
+        ids = np.asarray(dix.bnd2_sid[li])[grp[:, 0]]
+
+
+def _leg_inputs(dix, s, t):
+    """Per grouping level, _hier_leg's (row, grp, pos) for both sides,
+    as _combine_mid_h feeds it, with four rows appended: a sentinel-
+    only s side, a sentinel-only t side, both, and row 0 again with
+    every sentinel slot's value set to 0.  All their values are
+    finite, so only the sentinel handling keeps the first three +inf
+    and the fourth equal to row 0."""
+    us, ut = dix.agent_of[s], dix.agent_of[t]
+    fs, ft = dix.frag_of[us], dix.frag_of[ut]
+    rs = dix.brow[fs, dix.pos_in_frag[us]]
+    rt = dix.brow[ft, dix.pos_in_frag[ut]]
+    ids_s, ids_t = dix.bnd_super[fs], dix.bnd_super[ft]
+    levels = []
+    for li in range(len(dix.sf_of)):
+        sent = dix.sf_of[li].shape[0] - 1
+        ns, nt = jnp.full_like(ids_s[:1], sent), jnp.full_like(ids_t[:1],
+                                                               sent)
+        zs, zt = jnp.zeros_like(rs[:1]), jnp.zeros_like(rt[:1])
+        a_s = jnp.concatenate([ids_s, ns, ids_s[:1], ns, ids_s[:1]])
+        a_t = jnp.concatenate([ids_t, ids_t[:1], nt, nt, ids_t[:1]])
+        b_s = jnp.concatenate([rs, zs, rs[:1], zs, jnp.where(
+            ids_s[:1] == sent, 0.0, rs[:1])])
+        b_t = jnp.concatenate([rt, rt[:1], zt, zt, jnp.where(
+            ids_t[:1] == sent, 0.0, rt[:1])])
+        levels.append((b_s, dix.sf_of[li][a_s], dix.pos_in_sf[li][a_s],
+                       b_t, dix.sf_of[li][a_t], dix.pos_in_sf[li][a_t]))
+        gs, gt = dix.sf_of[li][ids_s], dix.sf_of[li][ids_t]
+        rs = _lift_compact(dix, li, rs, gs, dix.pos_in_sf[li][ids_s])
+        rt = _lift_compact(dix, li, rt, gt, dix.pos_in_sf[li][ids_t])
+        ids_s = dix.bnd2_sid[li][gs[:, 0]]
+        ids_t = dix.bnd2_sid[li][gt[:, 0]]
+    return levels
+
+
+def test_hier_leg_contraction_equals_gather(deep):
+    """_hier_leg's contraction layout (Pallas, interpret mode) is
+    bit-equal to its gather layout at every level, on sides in the
+    same group, in different groups, and sentinel-only."""
+    g, dix = deep
+    rng = np.random.default_rng(11)
+    frag = np.asarray(dix.frag_of)[np.asarray(dix.agent_of)]
+    nodes = np.nonzero(frag >= 0)[0]
+    s = rng.choice(nodes, 96).astype(np.int32)
+    t = rng.choice(nodes, 96).astype(np.int32)
+    # row 0: two nodes of one fragment that has sentinel slots, so its
+    # sides share a group at every level and carry sentinels
+    bs = np.asarray(dix.bnd_super)
+    padded = np.nonzero((bs == dix.sf_of[0].shape[0] - 1).any(axis=1))[0]
+    s[0], t[0] = rng.choice(np.nonzero(frag == padded[0])[0], 2)
+    groups_s = _side_groups(dix, frag[s])
+    groups_t = _side_groups(dix, frag[t])
+    for li, args in enumerate(_leg_inputs(dix, jnp.asarray(s),
+                                          jnp.asarray(t))):
+        same = groups_s[li] == groups_t[li]
+        assert same.any() and not same.all(), li
+        assert (args[1][0] != args[1][0, 0]).any(), li   # has sentinels
+        ref = np.asarray(_hier_leg(dix, li, *args, force="ref"))
+        kern = np.asarray(_hier_leg(dix, li, *args, force="pallas"))
+        np.testing.assert_array_equal(kern, ref)
+        assert np.isinf(ref[-4:-1]).all() and ref[-1] == ref[0]
+        assert np.isfinite(ref[0])
+        assert np.isinf(ref[:-4][~same]).all()
+        assert np.isfinite(ref[:-4][same]).any()
+
+
+def test_hier_leg_contraction_oracle(deep):
+    """Eight pairs through serve_cross on the contraction layout, two
+    for each leg that can answer them (same fragment; same level-0
+    group; same level-1 group only; different level-1 groups), each
+    == float64 Dijkstra."""
+    g, dix = deep
+    rng = np.random.default_rng(5)
+    frag = np.asarray(dix.frag_of)[np.asarray(dix.agent_of)]
+    agent = np.asarray(dix.agent_of)
+    nodes = np.nonzero(frag >= 0)[0]
+    kinds: dict = {}
+    while sum(len(v) for v in kinds.values()) < 8:
+        s, t = (int(x) for x in rng.choice(nodes, 2))
+        if agent[s] == agent[t]:
+            continue
+        g0, g1 = _side_groups(dix, frag[[s, t]])
+        kind = ("frag" if frag[s] == frag[t] else
+                "l0" if g0[0] == g0[1] else
+                "l1" if g1[0] == g1[1] else "top")
+        if len(kinds.setdefault(kind, [])) < 2:
+            kinds[kind].append((s, t))
+    for kind, pairs in kinds.items():
+        s, t = (jnp.asarray(x, jnp.int32) for x in zip(*pairs))
+        got = serve_cross(dix, s, t, with_local=kind == "frag",
+                          force="pallas")
+        for (a, b), d in zip(pairs, np.asarray(got)):
+            assert float(d) == dijkstra.pair(g, a, b), (kind, a, b)
 
 
 def test_ov_slot_map_matches_dense_table(built):

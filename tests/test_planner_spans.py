@@ -15,6 +15,7 @@ import pytest
 
 from repro.core.device_engine import (build_device_index, serve_cross,
                                       serve_cross_res, serve_hub)
+from repro.core import dist_engine
 from repro.core.dist_engine import QueryPlanner, pad_pow2
 from repro.core.graph import road_like
 from repro.core.supergraph import build_index
@@ -136,6 +137,59 @@ def test_query_hub_spans_one_bucket(world):
     assert bucket["padded"] == pad_pow2(5) and query["n"] == 5
     assert wait["parent"] == bucket["id"]
     assert bucket["parent"] == query["id"]
+
+
+def _level0_group_of_frag(dix) -> np.ndarray:
+    """Each fragment's level-0 group, from all its valid boundary
+    slots (-1: no boundary)."""
+    sf_of = np.asarray(dix.sf_of[0])
+    ids = np.asarray(dix.bnd_super)
+    valid = ids != sf_of.shape[0] - 1
+    grp = np.where(valid, sf_of[ids], sf_of.shape[0])
+    return np.where(valid.any(axis=1), grp.min(axis=1), -1)
+
+
+def test_leg_buckets_carry_same_group_counts(world):
+    """Each bucket whose program runs the same-group legs is tagged
+    ``same_l<li>``: how many of its pairs share the level-li group; the
+    other buckets carry no such tag."""
+    _, dix, planner, pairs = world
+    s, t = pairs[:, 0], pairs[:, 1]
+    events = _spans_of(planner.query, s, t)
+    plan = planner.plan(s, t)
+    grp = _level0_group_of_frag(dix)
+    frag = np.asarray(dix.frag_of)[np.asarray(dix.agent_of)]
+    buckets = [e["args"] for e in events if e["name"] == "planner.bucket"]
+    assert {b["case"] for b in buckets} >= set(QueryPlanner.LEG_CASES)
+    for b in buckets:
+        legs = {k: v for k, v in b.items() if k.startswith("same_l")}
+        if b["case"] not in QueryPlanner.LEG_CASES:
+            assert legs == {}
+            continue
+        idx = plan[b["case"]]
+        fs, ft = frag[s[idx]], frag[t[idx]]
+        ok = (fs >= 0) & (ft >= 0)
+        gs, gt = grp[np.where(ok, fs, 0)], grp[np.where(ok, ft, 0)]
+        want = int(np.count_nonzero(ok & (gs == gt) & (gs >= 0)))
+        assert legs == {"same_l0": want}
+        if b["case"] == "same_frag":
+            assert want == b["n"]
+
+
+def test_leg_counts_cost_nothing_unrecorded(world, monkeypatch):
+    """The same-group counts are computed only while spans are live."""
+    _, _, planner, pairs = world
+    calls = []
+    real = dist_engine._same_group_counts
+    monkeypatch.setattr(dist_engine, "_same_group_counts",
+                        lambda *a: calls.append(a) or real(*a))
+    tr = trace_mod.get_tracer()
+    assert not tr.enabled and not trace_mod.recording()
+    planner.query(pairs[:, 0], pairs[:, 1])
+    assert calls == []
+    _spans_of(planner.query, pairs[:, 0], pairs[:, 1])
+    assert len(calls) == sum(1 for c in QueryPlanner.LEG_CASES
+                             if planner.last_counts[c])
 
 
 def test_nothing_is_buffered_without_a_tracer_or_profiler(world):

@@ -15,12 +15,16 @@ The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and under several
 test workers only the one running this file does.
 """
+import types
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import device_engine
 from repro.kernels import floyd_warshall as fw
+from repro.kernels import ops
 from repro.kernels import label_merge as lm
 from repro.kernels import minplus_twoside as ts
 
@@ -89,3 +93,26 @@ def test_label_merge_compiles(one_chip):
     """The hub-label merge over full-width label rows."""
     w = S_TOP + 1
     _compile(lm.label_merge_pallas, one_chip, (BATCH, w), (BATCH, w))
+
+
+@pytest.mark.parametrize("groups,width", [(6, 56),      # level 1
+                                          (4, 432)])    # level 2
+def test_same_group_leg_compiles(one_chip, groups, width, monkeypatch):
+    """The same-group leg in its kernel layout: both sides scattered
+    into m2 = 1024 group coordinates and one twoside per real group,
+    at each grouping level's row width, for a 512-row bucket."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda force: (True, False))
+
+    def leg(closure, row_s, grp_s, pos_s, row_t, grp_t, pos_t):
+        dix = types.SimpleNamespace(sf_closure=(closure,))
+        return device_engine._hier_leg(dix, 0, row_s, grp_s, pos_s,
+                                       row_t, grp_t, pos_t)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    side = (arg((512, width)), arg((512, width), jnp.int32),
+            arg((512, width), jnp.int32))
+    text = jax.jit(leg).lower(arg((groups + 1, 1024, 1024)), *side,
+                              *side).compile().as_text()
+    assert text.count("tpu_custom_call") >= groups
