@@ -36,6 +36,7 @@ means bit-for-bit, regardless of association order.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import List
 
@@ -49,6 +50,7 @@ from . import hierarchy, padding
 from .supergraph import DislandIndex
 
 INF = np.float32(np.inf)
+#: padded DRA piece sizes (the witness closure takes any width)
 PIECE_BUCKETS = (8, 32, 128, 512, 2048)
 
 
@@ -579,16 +581,68 @@ def hier_super_stage(plan: BuildPlan, *, force=None) -> dict:
     }
 
 
-def _compose_minplus(U: jax.Array, M: jax.Array,
-                     chunk: int = 32) -> jax.Array:
-    """out[i, j] = min_b U[i, b] + M[b, j], chunked over b so the peak
-    intermediate stays [m2, chunk, mb'] (build-time helper for the
-    resident pre-lift; runs once per hot group per epoch)."""
-    out = jnp.full((U.shape[0], M.shape[1]), INF, U.dtype)
-    for i in range(0, U.shape[1], chunk):
-        out = jnp.minimum(out, jnp.min(
-            U[:, i:i + chunk, None] + M[None, i:i + chunk, :], axis=1))
-    return out
+@functools.partial(jax.jit, static_argnames=("sents",))
+def _group_chain(l2rows: tuple, sids: tuple, poss: tuple, gs: jax.Array,
+                 sents: tuple) -> tuple[jax.Array, jax.Array]:
+    """(U, ids) of one bottom-level group: U[p, x] is the exact confined
+    distance from its member at position p to the x-th boundary slot of
+    its TOP group, whose ids are ``ids``.  ``gs`` [L] is the group at
+    every level (groups nest upward), ``sents`` each level's sentinel
+    id.  One program per level-width ladder, whichever group it runs
+    for: the per-level lifts are (min,+) products (``ops.minplus``)."""
+    U = l2rows[0][gs[0]]
+    ids = sids[0][gs[0]]
+    for li in range(1, len(l2rows)):
+        M = l2rows[li][gs[li]][poss[li][ids]]        # [mb, mb']
+        M = jnp.where((ids != sents[li - 1])[:, None], M, INF)
+        U = ops.minplus(U, M)
+        ids = sids[li][gs[li]]
+    return U, ids
+
+
+def _chain_of(levels, fields: dict, g: int) -> tuple[jax.Array,
+                                                     jax.Array]:
+    """_group_chain for bottom-level group ``g`` of ``fields``'s
+    per-level tables."""
+    gs = [g]
+    for h in levels[1:]:
+        gs.append(int(h.sf_of_frag[gs[-1]]))
+    return _group_chain(fields["l2row"], fields["bnd2_sid"],
+                        fields["pos_in_sf"], jnp.asarray(gs, jnp.int32),
+                        tuple(int(h.S2) for h in levels[:-1]))
+
+
+@functools.partial(jax.jit, static_argnames=("width",))
+def _scatter_cols(U: jax.Array, ids: jax.Array, width: int) -> jax.Array:
+    """U's columns min-scattered to dense coordinates ``ids`` of a
+    [rows, width] row block (+inf elsewhere)."""
+    return jnp.full((U.shape[0], width), INF, U.dtype).at[:, ids].min(U)
+
+
+@jax.jit
+def _rows_through(rows: jax.Array, pos: jax.Array,
+                  table: jax.Array) -> jax.Array:
+    """out[a, :] = min_j rows[a, j] + table[pos[a, j], :]: each row's
+    boundary distances carried through a table of rows, chunked over j
+    so the gathered block stays [q, 8, width]."""
+    q, mb = rows.shape
+    c = min(8, mb)                       # mb is padded to a multiple of 8
+
+    def body(i, acc):
+        r_c = jax.lax.dynamic_slice_in_dim(rows, i * c, c, axis=1)
+        p_c = jax.lax.dynamic_slice_in_dim(pos, i * c, c, axis=1)
+        return jnp.minimum(acc, jnp.min(r_c[:, :, None] + table[p_c],
+                                        axis=1))
+
+    return jax.lax.fori_loop(0, mb // c, body,
+                             jnp.full((q, table.shape[1]), INF, rows.dtype))
+
+
+@jax.jit
+def _top_rows(U: jax.Array, d2: jax.Array, ids: jax.Array) -> jax.Array:
+    """A group chain closed over the whole top boundary: [m2, S_top+1]
+    = U (x) d2[ids] (sentinel ids land on d2's +inf row)."""
+    return ops.minplus(U, d2[ids])
 
 
 def resident_stage(plan: BuildPlan, fields: dict) -> dict | None:
@@ -634,61 +688,55 @@ def resident_stage(plan: BuildPlan, fields: dict) -> dict | None:
     if cap <= 0:
         return None
     hot = np.sort(np.argsort(-mass, kind="stable")[:min(cap, h0.nsf)])
-    l2rows, sids, poss = (fields["l2row"], fields["bnd2_sid"],
-                          fields["pos_in_sf"])
-    L = len(l2rows)
-    rows_out = []
-    for g in hot.tolist():
-        U = l2rows[0][g]                         # [m2, mb2_1]
-        ids = np.asarray(sids[0][g])             # next-overlay ids
-        gg = g
-        for li in range(1, L):
-            sent = levels[li - 1].S2             # ids' sentinel value
-            gg = int(levels[li].sf_of_frag[gg])  # groups nest upward
-            p = np.asarray(poss[li])[ids]
-            M = l2rows[li][gg][jnp.asarray(p)]   # [mb, mb']
-            M = jnp.where(jnp.asarray(ids != sent)[:, None], M, INF)
-            U = _compose_minplus(U, M)
-            ids = np.asarray(sids[li][gg])
-        dense = jnp.full((U.shape[0], stp1), INF, U.dtype)
-        rows_out.append(dense.at[:, jnp.asarray(ids)].min(U))
+    rows_out = [_scatter_cols(*_chain_of(levels, fields, g), width=stp1)
+                for g in hot.tolist()]
     R = len(rows_out)
     res_rows = jnp.stack(
         rows_out + [jnp.full((h0.m2, stp1), INF, jnp.float32)])
     rmap = np.full(h0.nsf, R, np.int32)
     rmap[hot] = np.arange(R, dtype=np.int32)
     res_of_frag = rmap[h0.sf_of_frag.astype(np.int64)]
-    top = h0.sf_of_frag.astype(np.int64)
-    for li in range(1, L):
-        top = levels[li].sf_of_frag.astype(np.int64)[top]
+    top = _top_group_of_frag(levels)
     return {
         "fields": {"res_rows": res_rows,
                    "res_of_frag": jnp.asarray(res_of_frag),
-                   "topgrp_of_frag": jnp.asarray(top.astype(np.int32))},
+                   "topgrp_of_frag": jnp.asarray(top)},
         # planner sidecars: fragment -> resident row (-1: cold) and
         # fragment -> TOP group (the exactness gate)
         "res_frag": np.where(res_of_frag < R, res_of_frag,
                              -1).astype(np.int32),
-        "topgrp_frag": top.astype(np.int32),
+        "topgrp_frag": top,
     }
+
+
+def _top_group_of_frag(levels) -> np.ndarray:
+    """int32 [k]: every fragment's TOP-level group."""
+    top = levels[0].sf_of_frag.astype(np.int64)
+    for h in levels[1:]:
+        top = h.sf_of_frag.astype(np.int64)[top]
+    return top.astype(np.int32)
 
 
 def hub_stage(plan: BuildPlan, fields: dict) -> dict | None:
     """Stage 2c: 2-hop hub labels for the hot serving tier (§15).
 
-    For every agent of a node in ``plan.hub_nodes`` (fragment-batched),
-    compose its label row — the exact overlay distance from the agent
-    to every TOP closure coordinate:
+    For every agent of a node in ``plan.hub_nodes``, compose its label
+    row — the exact overlay distance from the agent to every TOP
+    closure coordinate:
 
       lab[a, y] = min_{j, x} brow[f, p_a, j] + chain_f[j, x] + d2[x, y]
 
     where ``chain_f`` is the same per-level confined lift ladder the
     resident rows pre-compose (resident_stage), restricted to fragment
     f's boundary slots, and the trailing d2 contraction closes the row
-    over the whole top boundary.  Dense epochs skip the ladder:
-    lab[a] = brow row (min,+) d_super.  No Dijkstras anywhere — every
-    leg is a batched (min,+) product over tables the build already
-    carries.
+    over the whole top boundary.  Per bottom-level group g holding
+    labelled agents, the chain is closed over d2 once (``_top_rows``:
+    every member's distance to every top coordinate), and each agent's
+    label carries its boundary row through it (``_rows_through``).
+    Dense epochs skip the ladder: lab[a] = brow row (min,+) d_super.  No
+    Dijkstras anywhere — every leg is a batched (min,+) product over
+    tables the build already carries, in programs whose shapes are the
+    tables' and the pow2-padded agent count's.
 
     Exactness (the §15 merge argument): for endpoints in different TOP
     groups (dense: different fragments) the route must touch the top
@@ -714,69 +762,49 @@ def hub_stage(plan: BuildPlan, fields: dict) -> dict | None:
         return None
     brow = fields["brow"]
     levels = plan.hier
+    # (fragment, agent) order is the label row order, stable across
+    # build and refresh
     frag_a = plan.frag_of[agents]
-    pos_a = plan.pos_in_frag[agents]
-    # fragment-batched construction; (fragment, agent) order is the
-    # label row order, stable across build and refresh
     order = np.lexsort((agents, frag_a))
-    agents, frag_a, pos_a = agents[order], frag_a[order], pos_a[order]
+    agents, frag_a = agents[order], frag_a[order]
+    pos_a = plan.pos_in_frag[agents]
     H = int(agents.size)
-    rows_out = []
+
+    def through(sel, pos, table):
+        """_rows_through for the agents ``sel``, padded to pow2 rows."""
+        m = _pow2(int(sel.size), floor=8)
+        take = np.concatenate([sel, np.full(m - sel.size, sel[0])])
+        f, p = frag_a[take], pos_a[take]
+        rows = jnp.where(jnp.asarray(plan.bvalid[f]),
+                         brow[jnp.asarray(f), jnp.asarray(p)], INF)
+        return _rows_through(rows, jnp.asarray(pos[take]),
+                             table)[:sel.size]
+
     topgrp_frag = None
+    parts, sels = [], []
     if levels:
-        h0 = levels[0]
-        l2rows, sids = fields["l2row"], fields["bnd2_sid"]
-        poss, d2 = fields["pos_in_sf"], fields["d2"]
+        d2 = fields["d2"]
         width = int(d2.shape[0])
-        L = len(l2rows)
-        p0 = np.asarray(poss[0])
-        chains: dict[int, tuple] = {}
-
-        def group_chain(g: int) -> tuple:
-            """(U, ids): group g's confined member rows composed up the
-            ladder (same loop as resident_stage, kept compact — the
-            trailing d2 gather makes the dense scatter unnecessary)."""
-            got = chains.get(g)
-            if got is not None:
-                return got
-            U = l2rows[0][g]
-            ids = np.asarray(sids[0][g])
-            gg = g
-            for li in range(1, L):
-                sent = levels[li - 1].S2
-                gg = int(levels[li].sf_of_frag[gg])
-                p = np.asarray(poss[li])[ids]
-                M = l2rows[li][gg][jnp.asarray(p)]
-                M = jnp.where(jnp.asarray(ids != sent)[:, None], M, INF)
-                U = _compose_minplus(U, M)
-                ids = np.asarray(sids[li][gg])
-            chains[g] = (U, ids)
-            return chains[g]
-
-        for f in np.unique(frag_a).tolist():
-            sel = frag_a == f
-            U, ids = group_chain(int(h0.sf_of_frag[f]))
-            Z = U[jnp.asarray(p0[plan.bnd_super[f]])]    # [mb, mb_top]
-            Z = jnp.where(jnp.asarray(plan.bvalid[f])[:, None], Z, INF)
-            conf = _compose_minplus(
-                brow[f][jnp.asarray(pos_a[sel])], Z)
-            # sentinel ids land on d2's +inf row: absorbing, no mask
-            rows_out.append(_compose_minplus(conf, d2[jnp.asarray(ids)]))
-        top = h0.sf_of_frag.astype(np.int64)
-        for li in range(1, L):
-            top = levels[li].sf_of_frag.astype(np.int64)[top]
-        topgrp_frag = top.astype(np.int32)
+        # bottom-level positions of every agent's boundary slots
+        pos0 = np.asarray(fields["pos_in_sf"][0])[plan.bnd_super[frag_a]]
+        grp_a = levels[0].sf_of_frag[frag_a]
+        for g in np.unique(grp_a).tolist():
+            sel = np.nonzero(grp_a == g)[0]
+            U, ids = _chain_of(levels, fields, g)
+            parts.append(through(sel, pos0, _top_rows(U, d2, ids)))
+            sels.append(sel)
+        topgrp_frag = _top_group_of_frag(levels)
     else:
         d_super = fields["d_super"]
         width = int(d_super.shape[0])
-        for f in np.unique(frag_a).tolist():
-            sel = frag_a == f
-            M = d_super[jnp.asarray(plan.bnd_super[f])]  # [mb, S+1]
-            M = jnp.where(jnp.asarray(plan.bvalid[f])[:, None], M, INF)
-            rows_out.append(_compose_minplus(
-                brow[f][jnp.asarray(pos_a[sel])], M))
+        sel = np.arange(H)
+        parts.append(through(sel, plan.bnd_super[frag_a], d_super))
+        sels.append(sel)
+    inv = np.empty(H, np.int64)
+    inv[np.concatenate(sels)] = np.arange(H)
     hub_rows = jnp.concatenate(
-        rows_out + [jnp.full((1, width), INF, jnp.float32)])
+        [jnp.concatenate(parts)[jnp.asarray(inv)],
+         jnp.full((1, width), INF, jnp.float32)])
     hmap = np.full(plan.n, H, np.int32)          # sentinel row for all
     hmap[agents] = np.arange(H, dtype=np.int32)
     hub_agent = np.full(plan.n, -1, np.int32)    # planner gate sidecar
@@ -1237,7 +1265,8 @@ def refresh_hier_stage(plan: BuildPlan, dix: DeviceIndex,
         h = levels[-1]
         fast = None
         if cur.size and bool(np.all(h.l2_w[cur] <= lw_old[cur])):
-            fast = hierarchy.l2_decrease_stage(h, d2, d2_next, cur)
+            fast = hierarchy.l2_decrease_stage(h, d2, d2_next, cur,
+                                               force=force)
         if fast is not None:
             d2, d2_next = fast
             top_closure = "decrease"

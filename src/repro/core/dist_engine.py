@@ -108,6 +108,17 @@ def _same_group_counts(s, t, maps) -> dict:
             for li, g in enumerate(groups)}
 
 
+def _leg_rows(dix: DeviceIndex, padded: int) -> dict:
+    """{"leg_rows": rows the same-group legs stream for a bucket padded
+    to ``padded``}: on an accelerator every level runs one masked
+    contraction per real group over all the bucket's rows, so the sum
+    over grouping levels of (real groups x padded rows); {} on a dense
+    epoch.  Beside ``same_l<li>`` it gives the share of those rows that
+    does useful work."""
+    ng = sum(int(c.shape[0]) - 1 for c in dix.sf_closure)
+    return {"leg_rows": ng * padded} if ng else {}
+
+
 class QueryPlanner:
     """Bucket a query batch by case and dispatch per-case programs.
 
@@ -124,6 +135,7 @@ class QueryPlanner:
     CASES = ("same_dra", "same_frag", "cross_frag", "cross_res")
     #: the cases whose distance programs run the per-level same-group
     #: legs (_combine_mid_h); their planner.bucket spans carry same_l<li>
+    #: and leg_rows
     LEG_CASES = ("same_frag", "cross_frag")
 
     def __init__(self, dix: DeviceIndex, *, force=None,
@@ -336,8 +348,9 @@ class QueryPlanner:
                 if idx.size == 0:
                     continue
                 m = _pad_pow2(idx.size)
-                legs = (_same_group_counts(s[idx], t[idx],
-                                           self._maps_of(dix))
+                legs = ({**_same_group_counts(s[idx], t[idx],
+                                              self._maps_of(dix)),
+                         **_leg_rows(dix, m)}
                         if case in self.LEG_CASES and trace.recording()
                         else {})
                 with trace.span("planner.bucket", case=case,

@@ -502,9 +502,11 @@ def sf_stage(hier: HierPlan, *, force=None) -> tuple[jax.Array,
                                                      jax.Array]:
     """Per-level stage: batched witness FW over every group's induced
     overlay subgraph at the one pow2 tile shape [nsf, m2, m2] ->
-    (sf_closure, sf_next, l2row), sentinel block appended."""
+    (sf_closure, sf_next, l2row), sentinel block appended.  The span's
+    ``closure`` tag names the witness closure the tile width chose."""
     with trace.span("hierarchy.sf_stage", nsf=int(hier.nsf),
-                    m2=int(hier.m2)):
+                    m2=int(hier.m2),
+                    closure=ops.fw_next_variant(hier.m2, force)):
         closure, nxt = ops.fw_batch_next(jnp.asarray(hier.sf_adj),
                                          force=force)
         rows = l2row_from(closure, hier.bnd2_pos, hier.bnd2_valid)
@@ -524,10 +526,10 @@ def l2_overlay(hier: HierPlan) -> jax.Array:
     return jnp.asarray(m)
 
 
-def first_hops(adj: np.ndarray, dist: np.ndarray,
-               rows: Optional[np.ndarray] = None,
-               cols: Optional[np.ndarray] = None) -> np.ndarray:
-    """Canonical first-hop witnesses from (adjacency, exact closure).
+def top_first_hops(adj: np.ndarray, dist, rows=None, cols=None, *,
+                   force=None) -> jax.Array:
+    """Canonical first-hop witnesses from (adjacency, exact closure), on
+    the device (``ops.first_hops``).
 
     next[i, j] = the smallest k != i with adj[i, k] finite and
     adj[i, k] + dist[k, j] == dist[i, j]; -1 on the diagonal and for
@@ -538,34 +540,24 @@ def first_hops(adj: np.ndarray, dist: np.ndarray,
     ``d2_next``.  Positive edge weights make the chase strictly
     decrease dist[., j], so it always terminates.  ``rows``/``cols``
     restrict the output block (the decrease fast path re-derives only
-    the rows/columns whose inputs changed).
-    """
-    n = dist.shape[0]
-    rows = np.arange(n, dtype=np.int64) if rows is None else rows
-    cols = np.arange(n, dtype=np.int64) if cols is None else cols
-    a = adj.astype(np.float32, copy=True)
+    the rows/columns whose inputs changed)."""
+    a = np.array(adj, np.float32)
     np.fill_diagonal(a, INF)                     # k == i never witnesses
-    dc = dist[:, cols]                           # [n, m] candidate tails
-    out = np.full((rows.size, cols.size), -1, np.int32)
-    # chunk rows so the [c, n, m] candidate cube stays ~64 MiB
-    chunk = max(1, (1 << 24) // max(1, n * cols.size))
-    for i0 in range(0, rows.size, chunk):
-        ri = rows[i0:i0 + chunk]
-        ar = a[ri]                               # [c, n]
-        tgt = dist[np.ix_(ri, cols)]             # [c, m]
-        ok = (np.isfinite(ar)[:, :, None]
-              & (ar[:, :, None] + dc[None, :, :] == tgt[:, None, :]))
-        hop = np.argmax(ok, axis=1).astype(np.int32)
-        out[i0:i0 + chunk] = np.where(
-            ok.any(axis=1) & np.isfinite(tgt), hop, -1)
-    return out
+    dist = jnp.asarray(dist)
+    a = jnp.asarray(a if rows is None else a[rows])
+    tgt = dist if rows is None else dist[jnp.asarray(rows)]
+    if cols is not None:
+        dist = dist[:, jnp.asarray(cols)]
+        tgt = tgt[:, jnp.asarray(cols)]
+    hop = ops.first_hops(a, dist, tgt, force=force)
+    return jnp.where(jnp.isfinite(tgt), hop, -1)
 
 
 def l2_stage(hier: HierPlan, *, force=None) -> tuple[jax.Array,
                                                      jax.Array]:
     """Top stage: dense FW closure of the LAST level's boundary set ->
     (d2, d2_next) with the +inf sentinel row/col appended.  Witnesses
-    come from ``first_hops`` on the closed distances rather than the
+    come from ``top_first_hops`` on the closed distances rather than the
     FW kernel's pivot-order-dependent tie-breaks, so the decrease-only
     refresh fast path (``l2_decrease_stage``) can reproduce them
     array-equal without re-running the full closure."""
@@ -576,10 +568,9 @@ def l2_stage(hier: HierPlan, *, force=None) -> tuple[jax.Array,
         if S2 == 0 or hier.l2_src.size == 0:
             return d2, d2_next
         adj = np.asarray(l2_overlay(hier))
-        d_s = np.asarray(ops.fw_apsp(jnp.asarray(adj), force=force))
-        n_s = first_hops(adj, d_s)
-        return (d2.at[:S2, :S2].set(d_s),
-                d2_next.at[:S2, :S2].set(jnp.asarray(n_s)))
+        d_s = ops.fw_apsp(jnp.asarray(adj), force=force)
+        n_s = top_first_hops(adj, d_s, force=force)
+        return (d2.at[:S2, :S2].set(d_s), d2_next.at[:S2, :S2].set(n_s))
 
 
 #: decrease fast path bail-out: above this fraction of S2 touched, the
@@ -589,7 +580,7 @@ DECREASE_MAX_FRAC = 8
 
 def l2_decrease_stage(hier: HierPlan, d2_old: jax.Array,
                       d2_next_old: jax.Array,
-                      changed_slots: np.ndarray
+                      changed_slots: np.ndarray, *, force=None
                       ) -> Optional[tuple[jax.Array, jax.Array]]:
     """Decrease-only incremental top closure (DESIGN.md §14).
 
@@ -605,7 +596,7 @@ def l2_decrease_stage(hier: HierPlan, d2_old: jax.Array,
     with weight >= its new true distance), and any strictly shorter new
     path splits at its first/last changed-edge endpoints, both in U, so
     the three-factor contraction reaches it.  Witnesses re-derive via
-    ``first_hops`` only on the rows/columns whose adjacency row or
+    ``top_first_hops`` only on the rows/columns whose adjacency row or
     closure column changed; everything else carries over — for (i, j)
     with both outside that set, adj[i, :], dist[:, j] and dist[i, j]
     are all unchanged, so the canonical witness is too.
@@ -655,11 +646,12 @@ def l2_decrease_stage(hier: HierPlan, d2_old: jax.Array,
             u_ids, np.nonzero((d_new != d_old).any(axis=1))[0])
         adj = np.asarray(l2_overlay(hier))
         nxt_new = nxt_old.copy()
-        nxt_new[touched, :] = first_hops(adj, d_new, rows=touched)
+        nxt_new[touched, :] = np.asarray(top_first_hops(
+            adj, d_new, rows=touched, force=force))
         rest = np.setdiff1d(np.arange(S2, dtype=np.int64), touched)
         if rest.size and touched.size:
-            nxt_new[np.ix_(rest, touched)] = first_hops(
-                adj, d_new, rows=rest, cols=touched)
+            nxt_new[np.ix_(rest, touched)] = np.asarray(top_first_hops(
+                adj, d_new, rows=rest, cols=touched, force=force))
         d2 = jnp.full((S2 + 1, S2 + 1), INF, jnp.float32)
         d2_next = jnp.full((S2 + 1, S2 + 1), -1, jnp.int32)
         return (d2.at[:S2, :S2].set(jnp.asarray(d_new)),

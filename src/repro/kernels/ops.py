@@ -11,6 +11,7 @@ from typing import Literal, Optional
 
 import jax
 
+from . import first_hop as _fh
 from . import floyd_warshall as _fw
 from . import label_merge as _lm
 from . import minplus as _mp
@@ -106,25 +107,44 @@ def fw_batch_next(d: jax.Array, *, force: Force = None
                   ) -> tuple[jax.Array, jax.Array]:
     """Witness-carrying batched APSP -> (dist, nxt); dist bit-identical
     to fw_batch, nxt[b, i, j] = first hop of a shortest i -> j path in
-    batch entry b (-1: unreachable / diagonal)."""
+    batch entry b (-1: unreachable / diagonal).  One Pallas closure for
+    every width (``fw_batch_next_blocked``): its ties depend on the tile
+    width and nothing else, so a build and a refresh of one level, which
+    close it at one width, always agree."""
     pallas, interp = _use_pallas(force)
     if pallas:
-        return _fw.fw_batch_next_pallas(d, interpret=interp)
+        return _fw.fw_batch_next_blocked(d, interpret=interp)
     return _ref.fw_batch_next_ref(d)
+
+
+def fw_next_variant(n: int, force: Force = None) -> str:
+    """The witness closure ``fw_batch_next`` runs at tile width ``n``:
+    "whole_tile" (one pivot block), "blocked" or "ref" (the jnp path)."""
+    if not _use_pallas(force)[0]:
+        return "ref"
+    return "blocked" if n > _fw.FW_BLOCK else "whole_tile"
 
 
 def fw_next(d: jax.Array, *, force: Force = None
             ) -> tuple[jax.Array, jax.Array]:
-    """Witness-carrying APSP for a single [n, n] matrix.
-
-    The Pallas path runs the whole matrix as a batch of one (the SUPER
-    overlay is a few hundred nodes, comfortably VMEM-resident; a blocked
-    witness closure is not worth its complexity at that size)."""
-    pallas, interp = _use_pallas(force)
+    """Witness-carrying APSP for a single [n, n] matrix: a batch of one
+    (the dense overlay closure)."""
+    pallas, _ = _use_pallas(force)
     if pallas:
-        dist, nxt = _fw.fw_batch_next_pallas(d[None], interpret=interp)
+        dist, nxt = fw_batch_next(d[None], force=force)
         return dist[0], nxt[0]
     return _ref.fw_next_ref(d)
+
+
+def first_hops(adj: jax.Array, dist: jax.Array, target: jax.Array, *,
+               force: Force = None) -> jax.Array:
+    """Canonical first hops: out[i, j] = the smallest k with
+    adj[i, k] + dist[k, j] == target[i, j], -1 where none is (the top
+    closure's witnesses, ``hierarchy.top_first_hops``)."""
+    pallas, interp = _use_pallas(force)
+    if pallas:
+        return _fh.first_hop_pallas(adj, dist, target, interpret=interp)
+    return _ref.first_hops_ref(adj, dist, target)
 
 
 def fw_apsp(d: jax.Array, *, block: int = 128,

@@ -5,9 +5,23 @@ import jax
 import jax.numpy as jnp
 
 
-def minplus_ref(a: jax.Array, b: jax.Array) -> jax.Array:
-    """C[i,j] = min_k A[i,k] + B[k,j] (tropical GEMM)."""
-    return jnp.min(a[:, :, None] + b[None, :, :], axis=1)
+def minplus_ref(a: jax.Array, b: jax.Array, *, chunk: int = 16
+                ) -> jax.Array:
+    """C[i,j] = min_k A[i,k] + B[k,j] (tropical GEMM), k-chunked so the
+    peak intermediate is [m, chunk, n], never the [m, k, n] cube."""
+    m, k = a.shape
+    kp = -(-k // chunk) * chunk
+    a_p = jnp.pad(a, ((0, 0), (0, kp - k)), constant_values=jnp.inf)
+    b_p = jnp.pad(b, ((0, kp - k), (0, 0)), constant_values=jnp.inf)
+
+    def body(i, acc):
+        a_c = jax.lax.dynamic_slice_in_dim(a_p, i * chunk, chunk, axis=1)
+        b_c = jax.lax.dynamic_slice_in_dim(b_p, i * chunk, chunk, axis=0)
+        return jnp.minimum(acc, jnp.min(a_c[:, :, None] + b_c[None],
+                                        axis=1))
+
+    return jax.lax.fori_loop(0, kp // chunk, body,
+                             jnp.full((m, b.shape[1]), jnp.inf, a.dtype))
 
 
 def minplus_accum_ref(c: jax.Array, a: jax.Array, b: jax.Array
@@ -89,6 +103,30 @@ def minplus_twoside_argmin_ref(rows: jax.Array, d: jax.Array,
                    jnp.take_along_axis(accx, jnp.clip(wy, 0)[:, None],
                                        axis=1)[:, 0], -1)
     return out, wx, wy
+
+
+def first_hops_ref(adj: jax.Array, dist: jax.Array, target: jax.Array,
+                   *, chunk: int = 8) -> jax.Array:
+    """out[i, j] = the smallest k with adj[i, k] + dist[k, j] ==
+    target[i, j] (-1: none), k-chunked so the peak intermediate is
+    [r, chunk, c].  Same contract as first_hop.first_hop_pallas."""
+    r, n = adj.shape
+    c = dist.shape[1]
+    n_p = -(-n // chunk) * chunk
+    adj_p = jnp.pad(adj, ((0, 0), (0, n_p - n)), constant_values=jnp.inf)
+    dist_p = jnp.pad(dist, ((0, n_p - n), (0, 0)), constant_values=jnp.inf)
+    none = jnp.int32(n_p)
+
+    def body(i, acc):
+        a_c = jax.lax.dynamic_slice_in_dim(adj_p, i * chunk, chunk, axis=1)
+        d_c = jax.lax.dynamic_slice_in_dim(dist_p, i * chunk, chunk, axis=0)
+        hit = a_c[:, :, None] + d_c[None, :, :] == target[:, None, :]
+        k = i * chunk + jnp.arange(chunk, dtype=jnp.int32)[None, :, None]
+        return jnp.minimum(acc, jnp.min(jnp.where(hit, k, none), axis=1))
+
+    acc = jax.lax.fori_loop(0, n_p // chunk, body,
+                            jnp.full((r, c), none, jnp.int32))
+    return jnp.where(acc == none, -1, acc)
 
 
 def fw_ref(d: jax.Array) -> jax.Array:

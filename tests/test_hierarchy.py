@@ -33,7 +33,7 @@ from repro.core.device_engine import (_hier_leg, _lift_compact,
                                       resolve_hierarchy_levels,
                                       serve_cross, serve_one_to_all,
                                       serve_step)
-from repro.core.dist_engine import EpochedEngine
+from repro.core.dist_engine import EpochedEngine, QueryPlanner
 from repro.core.graph import road_like, traffic_updates
 from repro.core.paths import path_weight
 from repro.core.supergraph import build_index, reweight_index
@@ -418,3 +418,60 @@ def test_refresh_replace_keeps_sidecars():
     # and the dense path still carries its dense table
     eng1 = EpochedEngine(road_like(400, seed=2), hierarchy_levels=1)
     assert isinstance(eng1.dix.host_ov_slot, np.ndarray)
+
+
+@pytest.fixture(scope="module")
+def four():
+    """hierarchy_levels=4: three grouping levels, each with at least
+    two real groups, and the dense index of the same graph."""
+    g = road_like(6000, seed=7)
+    ix = build_index(g)
+    dix4 = build_device_index(ix, hierarchy_levels=4)
+    dix1 = build_device_index(ix, hierarchy_levels=1)
+    assert len(dix4.sf_of) == 3
+    assert all(c.shape[0] - 1 >= 2 for c in dix4.sf_closure)
+    rng = np.random.default_rng(17)
+    pairs = rng.integers(0, g.n, (96, 2)).astype(np.int32)
+    return g, ix, dix4, dix1, pairs
+
+
+def test_four_levels_serve_dijkstra(four):
+    """Three grouping levels through QueryPlanner on the kernel layout
+    (interpret mode): every sampled pair == float64 Dijkstra, and some
+    pairs need each of the three levels' same-group legs."""
+    g, _ix, dix4, _dix1, pairs = four
+    got = QueryPlanner(dix4, force="pallas").query(pairs[:, 0],
+                                                   pairs[:, 1])
+    for (s, t), d in zip(pairs, got):
+        assert float(d) == dijkstra.pair(g, int(s), int(t)), (s, t)
+    frag = np.asarray(dix4.frag_of)[np.asarray(dix4.agent_of)]
+    gs, gt = _side_groups(dix4, frag[pairs[:, 0]]), \
+        _side_groups(dix4, frag[pairs[:, 1]])
+    for li in range(3):
+        assert ((gs[li] == gt[li]) & (gs[li] >= 0)).any(), li
+
+
+def test_four_levels_equal_dense(four):
+    """The four-level index answers array-equal to the dense closure,
+    through the planner on the kernel layout."""
+    _g, _ix, dix4, dix1, pairs = four
+    s, t = pairs[:, 0], pairs[:, 1]
+    np.testing.assert_array_equal(
+        QueryPlanner(dix4, force="pallas").query(s, t),
+        QueryPlanner(dix1, force="pallas").query(s, t))
+
+
+def test_four_levels_refresh_equals_rebuild(four):
+    """Refresh == rebuild on every per-level table of the three
+    grouping levels and the top closure, across a jam and a clearance."""
+    g = four[0]
+    engine = EpochedEngine(g, hierarchy_levels=4)
+    assert engine.dix.hierarchy_levels == 4
+    for r in range(2):
+        u, v, w = traffic_updates(engine.g, frac=0.03, seed=80 + r,
+                                  localized=bool(r % 2))
+        engine.apply_updates(u, v, w)
+        sdix = build_device_index(reweight_index(engine.ix, engine.g),
+                                  hierarchy_levels=4)
+        eq = index_fields_equal(engine.dix, sdix, REFRESHED_FIELDS)
+        assert not [f for f, ok in eq.items() if not ok], engine.epoch

@@ -8,6 +8,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import dijkstra
 from repro.core.graph import random_graph
+from repro.kernels import floyd_warshall as fw
 from repro.kernels import ops, ref
 
 
@@ -305,3 +306,104 @@ def test_minplus_triangle_inequality(seed):
     apsp = ops.fw_batch(d, force="ref")[0]
     sq = ref.minplus_ref(apsp, apsp)
     assert bool(jnp.all(sq >= apsp - 1e-4))
+
+
+# ---- wide tiles: the blocked witness closure and the top first hops -------
+def _tied_adj(b, n, rng, inf_frac=0.8):
+    """Symmetric integer adjacencies with weights 1..4: short paths of
+    equal length everywhere, so the witnesses meet many ties."""
+    d = rng.integers(1, 5, (b, n, n)).astype(np.float32)
+    d[rng.random((b, n, n)) < inf_frac] = np.inf
+    return np.minimum(d, np.transpose(d, (0, 2, 1)))
+
+
+def _assert_valid_hops(adj, dist, nxt):
+    """Every hop h of (i, j) is adjacent to i and starts a shortest
+    path: adj[i, h] + dist[h, j] == dist[i, j]; -1 exactly on the
+    diagonal and the unreachable pairs."""
+    n = adj.shape[-1]
+    for a, d, h in zip(adj, dist, nxt):
+        a = a.copy()
+        np.fill_diagonal(a, np.inf)
+        fin = np.isfinite(d) & ~np.eye(n, dtype=bool)
+        np.testing.assert_array_equal(h[~fin], -1)
+        hc = np.clip(h, 0, n - 1)
+        ah = np.take_along_axis(a, hc, axis=1)
+        dh = d[hc, np.arange(n)[None, :]]
+        assert ((h >= 0) & np.isfinite(ah) & (ah + dh == d))[fin].all()
+
+
+@pytest.mark.parametrize("b,n,block", [(2, 64, 8), (3, 40, 8), (1, 64, 16)])
+def test_fw_batch_next_blocked_witness(b, n, block):
+    """The blocked witness closure (interpret mode, block scaled down):
+    distances bit-equal to the reference, every witness a valid first
+    hop of a shortest path, ties included; n = 40 pads to the block."""
+    rng = np.random.default_rng(b * 1000 + n + block)
+    adj = _tied_adj(b, n, rng)
+    dist, nxt = map(np.asarray, fw.fw_batch_next_blocked(
+        jnp.asarray(adj), block=block, interpret=True))
+    want, _ = map(np.asarray, ops.fw_batch_next(jnp.asarray(adj),
+                                                force="ref"))
+    np.testing.assert_array_equal(dist, want)
+    _assert_valid_hops(adj, dist, nxt)
+
+
+def test_fw_batch_next_one_block_is_the_whole_tile():
+    """A tile no wider than the pivot block is closed by the whole-tile
+    witness kernel alone (same distances and hops); wider tiles take
+    the panels too.  ops.fw_batch_next runs this one closure."""
+    assert ops.fw_next_variant(fw.FW_BLOCK, "pallas") == "whole_tile"
+    assert ops.fw_next_variant(fw.FW_BLOCK + 1, "pallas") == "blocked"
+    assert ops.fw_next_variant(4096, "ref") == "ref"
+    adj = jnp.asarray(_tied_adj(2, 24, np.random.default_rng(3)))
+    want = fw._fw_next_tiles(*ref.fw_next_init(adj), True)
+    for got in (fw.fw_batch_next_blocked(adj, interpret=True),
+                ops.fw_batch_next(adj, force="pallas")):
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _first_hops_np(adj, dist, rows=None, cols=None):
+    """The plain reference of the canonical first hops: the smallest
+    k != i with adj[i, k] finite and adj[i, k] + dist[k, j] ==
+    dist[i, j], -1 where none is or dist[i, j] is +inf."""
+    n = dist.shape[0]
+    rows = np.arange(n) if rows is None else rows
+    cols = np.arange(n) if cols is None else cols
+    a = adj.astype(np.float32, copy=True)
+    np.fill_diagonal(a, np.inf)
+    out = np.full((rows.size, cols.size), -1, np.int32)
+    for r, i in enumerate(rows):
+        tgt = dist[i, cols]
+        ok = (np.isfinite(a[i])[:, None]
+              & (a[i][:, None] + dist[:, cols] == tgt[None, :]))
+        out[r] = np.where(ok.any(axis=0) & np.isfinite(tgt),
+                          np.argmax(ok, axis=0), -1)
+    return out
+
+
+@pytest.mark.parametrize("force", ["ref", "pallas"])
+@pytest.mark.parametrize("n", [37, 150])
+def test_top_first_hops_match_the_host_reference(n, force):
+    """Top witnesses on the device (kernel in interpret mode, and the
+    jnp reference) array-equal to the host reference on random closures
+    with planted ties and an unreachable part, whole and restricted to
+    rows or to rows x columns."""
+    from repro.core.hierarchy import top_first_hops
+
+    rng = np.random.default_rng(n)
+    adj = _tied_adj(1, n, rng, inf_frac=0.9)[0]
+    adj[: n // 5, n // 5:] = adj[n // 5:, : n // 5] = np.inf
+    dist = np.asarray(ref.fw_ref(jnp.asarray(adj)))
+    assert np.isinf(dist).any()
+    got = np.asarray(top_first_hops(adj, dist, force=force))
+    np.testing.assert_array_equal(got, _first_hops_np(adj, dist))
+    rows = np.sort(rng.choice(n, 9, replace=False))
+    cols = np.sort(rng.choice(n, 13, replace=False))
+    np.testing.assert_array_equal(
+        np.asarray(top_first_hops(adj, dist, rows=rows, force=force)),
+        _first_hops_np(adj, dist, rows=rows))
+    np.testing.assert_array_equal(
+        np.asarray(top_first_hops(adj, dist, rows=rows, cols=cols,
+                                  force=force)),
+        _first_hops_np(adj, dist, rows=rows, cols=cols))

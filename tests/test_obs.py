@@ -239,11 +239,15 @@ def test_span_nesting_and_ordering_invariants():
 def test_span_depth_is_per_thread():
     tr = Tracer(enabled=True)
     seen = {}
+    # all four threads stay alive inside their spans together, so no
+    # thread id is reused by a later thread
+    together = threading.Barrier(4, timeout=10)
 
     def work(tid):
         with tr.span(f"t{tid}"):
             time.sleep(0.005)
             seen[tid] = tr.depth
+            together.wait()
     threads = [threading.Thread(target=work, args=(t,))
                for t in range(4)]
     for t in threads:

@@ -192,6 +192,38 @@ def test_leg_counts_cost_nothing_unrecorded(world, monkeypatch):
                              if planner.last_counts[c])
 
 
+def test_leg_buckets_carry_leg_rows(world):
+    """Each bucket whose program runs the same-group legs is tagged
+    ``leg_rows``: the real groups of every grouping level times the
+    bucket's padded rows, the rows its masked contractions stream."""
+    _, dix, planner, pairs = world
+    events = _spans_of(planner.query, pairs[:, 0], pairs[:, 1])
+    groups = sum(np.asarray(c).shape[0] - 1 for c in dix.sf_closure)
+    buckets = [e["args"] for e in events if e["name"] == "planner.bucket"]
+    assert {b["case"] for b in buckets} >= set(QueryPlanner.LEG_CASES)
+    for b in buckets:
+        if b["case"] in QueryPlanner.LEG_CASES:
+            assert b["leg_rows"] == groups * b["padded"]
+            assert b["leg_rows"] >= b["same_l0"]
+        else:
+            assert "leg_rows" not in b
+
+
+def test_leg_rows_cost_nothing_unrecorded(world, monkeypatch):
+    """``leg_rows`` is computed only while spans are live."""
+    _, _, planner, pairs = world
+    calls = []
+    real = dist_engine._leg_rows
+    monkeypatch.setattr(dist_engine, "_leg_rows",
+                        lambda *a: calls.append(a) or real(*a))
+    assert not trace_mod.recording()
+    planner.query(pairs[:, 0], pairs[:, 1])
+    assert calls == []
+    _spans_of(planner.query, pairs[:, 0], pairs[:, 1])
+    assert len(calls) == sum(1 for c in QueryPlanner.LEG_CASES
+                             if planner.last_counts[c])
+
+
 def test_nothing_is_buffered_without_a_tracer_or_profiler(world):
     _, _, planner, pairs = world
     tr = trace_mod.get_tracer()

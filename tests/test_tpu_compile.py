@@ -9,7 +9,9 @@ is the same on every host: 128 fragment tiles at maxf = 496, DRA
 pieces at caps 8 and 32, group closures at m2 = 1024 (6 groups on
 level 1, 4 on level 2), a top closure over S_top = 1867 boundary nodes
 (d2 and the label rows are S_top + 1 = 1868 wide), and serve batches
-up to 1024 queries.
+up to 1024 queries.  The road250k preset adds wider group closures
+(10 groups at m2 = 2048, 6 at 2048, 3 at 4096) and a top closure over
+6,470 boundary nodes.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and under several
@@ -23,12 +25,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import device_engine
+from repro.kernels import first_hop as fh
 from repro.kernels import floyd_warshall as fw
 from repro.kernels import ops
 from repro.kernels import label_merge as lm
 from repro.kernels import minplus_twoside as ts
 
 S_TOP = 1867
+S_TOP_250K = 6470
 BATCH = 1024
 
 
@@ -71,9 +75,23 @@ def _compile(fn, sharding, *shapes):
                                      (75, 32),
                                      (6, 1024)])     # group closures
 def test_fw_batch_next_compiles(one_chip, batch, n):
-    """Witness FW at every tile width the road64k build runs; m2 = 1024
-    needs a scoped-VMEM limit above the 16 MiB default."""
-    _compile(fw.fw_batch_next_pallas, one_chip, (batch, n, n))
+    """The witness closure at every tile width the road64k build runs:
+    one diagonal block up to 256 wide, panels above."""
+    _compile(fw.fw_batch_next_blocked, one_chip, (batch, n, n))
+
+
+@pytest.mark.parametrize("batch,n", [(10, 2048), (3, 4096)])
+def test_blocked_witness_closure_compiles(one_chip, batch, n):
+    """The witness closure at road250k's group widths, where a whole
+    tile's operands would not fit VMEM."""
+    _compile(fw.fw_batch_next_blocked, one_chip, (batch, n, n))
+
+
+def test_top_first_hops_compile(one_chip):
+    """road250k's top witnesses: first hops over the 6,470-node top
+    closure on the device."""
+    shape = (S_TOP_250K, S_TOP_250K)
+    _compile(fh.first_hop_pallas, one_chip, shape, shape, shape)
 
 
 def test_fw_blocked_compiles(one_chip):
@@ -95,12 +113,10 @@ def test_label_merge_compiles(one_chip):
     _compile(lm.label_merge_pallas, one_chip, (BATCH, w), (BATCH, w))
 
 
-@pytest.mark.parametrize("groups,width", [(6, 56),      # level 1
-                                          (4, 432)])    # level 2
-def test_same_group_leg_compiles(one_chip, groups, width, monkeypatch):
-    """The same-group leg in its kernel layout: both sides scattered
-    into m2 = 1024 group coordinates and one twoside per real group,
-    at each grouping level's row width, for a 512-row bucket."""
+def _leg_text(sharding, groups, width, m2, monkeypatch):
+    """The compiled text of the same-group leg in its kernel layout for
+    a 512-row bucket: both sides scattered into m2 group coordinates,
+    one twoside per real group."""
     monkeypatch.setattr(ops, "_use_pallas", lambda force: (True, False))
 
     def leg(closure, row_s, grp_s, pos_s, row_t, grp_t, pos_t):
@@ -109,10 +125,24 @@ def test_same_group_leg_compiles(one_chip, groups, width, monkeypatch):
                                        row_t, grp_t, pos_t)
 
     def arg(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     side = (arg((512, width)), arg((512, width), jnp.int32),
             arg((512, width), jnp.int32))
-    text = jax.jit(leg).lower(arg((groups + 1, 1024, 1024)), *side,
+    return jax.jit(leg).lower(arg((groups + 1, m2, m2)), *side,
                               *side).compile().as_text()
+
+
+@pytest.mark.parametrize("groups,width", [(6, 56),      # level 1
+                                          (4, 432)])    # level 2
+def test_same_group_leg_compiles(one_chip, groups, width, monkeypatch):
+    """road64k's legs: m2 = 1024, at each grouping level's row width."""
+    text = _leg_text(one_chip, groups, width, 1024, monkeypatch)
     assert text.count("tpu_custom_call") >= groups
+
+
+def test_same_group_leg_compiles_at_4096(one_chip, monkeypatch):
+    """road250k's third grouping level: 3 groups at m2 = 4096, rows
+    1,616 wide (the second level's boundary slots)."""
+    text = _leg_text(one_chip, 3, 1616, 4096, monkeypatch)
+    assert text.count("tpu_custom_call") >= 3
