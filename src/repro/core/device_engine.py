@@ -1569,46 +1569,97 @@ def _overlay_size(dix: DeviceIndex) -> int:
             else dix.d_super.shape[0])
 
 
+def leg_tiles(counts):
+    """The grouped legs' tile rule -> (bq, tiles): a group with
+    counts[g] live rows takes tiles[g] = ceil(counts[g] / bq) q tiles
+    of bq rows.  The device schedule (_leg_schedule) and the planner's
+    ``leg_rows`` count both use it, on jnp and numpy counts alike."""
+    bq = ops.GROUPED_TILE_ROWS
+    return bq, -(-counts // bq)
+
+
+def _leg_key(grp_s, grp_t, valid, ng: int):
+    """Each row's key for the grouped legs at one level: the group both
+    its sides lie in, or ``ng`` (no live row) where they lie in
+    different groups, a side is sentinel-only (group ``ng``) or
+    ``valid`` (None: every row) is False."""
+    gs = grp_s[:, 0]
+    keyed = (gs == grp_t[:, 0]) & (gs < ng)
+    if valid is not None:
+        keyed &= valid
+    return jnp.where(keyed, gs, ng)
+
+
+def _leg_schedule(key, ng: int):
+    """Grouped-tile schedule of one level's leg, static in shape, from
+    each row's key (its group, or ``ng``: no live row) -> (bq, slot,
+    tile_group, live).  The live rows of group g fill tiles[g] tiles of
+    bq rows (leg_tiles), groups in order and rows in their batch order;
+    slot [q] is each row's place in that [T * bq] layout (T * bq, out
+    of range, for a keyless row), tile_group [T] each tile's group and
+    live the live tile count.  T = ceil(q / bq) + ng bounds the live
+    tiles, since each group wastes less than one tile."""
+    q = key.shape[0]
+    hit = key[:, None] == jnp.arange(ng, dtype=key.dtype)[None, :]
+    counts = jnp.sum(hit, axis=0, dtype=jnp.int32)
+    bq, tiles = leg_tiles(counts)
+    n_tiles = -(-q // bq) + ng
+    ends = jnp.cumsum(tiles)
+    rank = jnp.sum(jnp.where(hit, jnp.cumsum(hit, axis=0, dtype=jnp.int32),
+                             0), axis=1) - 1
+    first = (ends - tiles)[jnp.minimum(key, ng - 1)]
+    slot = jnp.where(key < ng, first * bq + rank, n_tiles * bq)
+    tile_group = jnp.sum(ends[None, :] <= jnp.arange(n_tiles)[:, None],
+                         axis=1, dtype=jnp.int32)
+    return bq, slot, jnp.minimum(tile_group, ng - 1), ends[-1]
+
+
 def _hier_leg(dix: DeviceIndex, li: int, row_s, grp_s, pos_s,
-              row_t, grp_t, pos_t, *, force=None):
+              row_t, grp_t, pos_t, *, valid=None, force=None):
     """Same-group leg at grouping level ``li``: min over slot pairs
     (i, j) in the SAME level-li group of
     row_s[i] + sf_closure[li][g, pos_i, pos_j] + row_t[j].
 
     Accelerator: all valid slots of one side lie in one group (groups
     nest; _lift_compact relies on it too), so the leg is a two-sided
-    (min,+) contraction against that group's closure block.  Each side
-    is scattered into the group's m2 coordinates (its sentinel slots,
-    whose group differs from slot 0's, are dropped), and each real
-    group g runs the fused minplus_twoside over the rows of the
-    queries whose two sides both lie in g; a query whose sides lie in
-    different groups gets +inf, as the gather's mask gives it.  That
-    is ng * q * m2^2 pairs per level, streamed through VMEM, in place
-    of a per-query scalar gather.  CPU/ref: the gather, chunked over
-    the s-axis so the gathered block stays [q, 8, width].  Both are
+    (min,+) contraction against that group's closure block, and only
+    the rows whose two sides share a real group can win.  Each row is
+    keyed by that group (_leg_key), or by the sentinel ``ng`` where its
+    sides lie in different groups, a side is sentinel-only, or
+    ``valid`` is False (the planner's s = t filler rows, which share
+    every group); _leg_schedule packs the keyed rows into q tiles of
+    one group each.  Each side is scattered straight into that grouped
+    layout in its group's m2 coordinates (its sentinel slots, whose
+    group differs from slot 0's, are dropped, and so are keyless
+    rows), one grouped minplus_twoside contracts every tile against
+    its own group's closure block, and each keyed row reads its slot
+    back; a keyless row gets +inf, as the gather's mask gives it.
+    That streams sum_g ceil(n_g / bq) * bq rows per level, n_g the
+    rows keyed g.
+    CPU/ref: the gather, chunked over the s-axis so the gathered block
+    stays [q, 8, width], over every row (``valid`` unused).  Both are
     exact: every sum is an integer-valued f32 below 2**24."""
     q, mbs = row_s.shape
     mbt = row_t.shape[1]
     if ops.use_pallas(force):
         ng, m2 = dix.sf_closure[li].shape[:2]
         ng -= 1                                  # last group: sentinel
-        qi = jnp.arange(q, dtype=jnp.int32)[:, None]
-
-        def dense(row, grp, pos):
-            own = jnp.where(grp == grp[:, :1], pos, m2)  # m2: dropped
-            return jnp.full((q, m2), INF, row.dtype).at[qi, own].min(
-                row, mode="drop")
-
+        key = _leg_key(grp_s, grp_t, valid, ng)
         with jax.named_scope(f"hier_leg_l{li}"):
-            rs, rt = dense(row_s, grp_s, pos_s), dense(row_t, grp_t, pos_t)
-            gs, gt = grp_s[:, 0], grp_t[:, 0]
-            va = jnp.full((q,), INF, row_s.dtype)
-            for g in range(ng):
-                both = ((gs == g) & (gt == g))[:, None]
-                va = jnp.minimum(va, ops.minplus_twoside(
-                    jnp.where(both, rs, INF), dix.sf_closure[li][g], rt,
-                    force=force))
-            return va
+            bq, slot, tile_group, live = _leg_schedule(key, ng)
+            n_rows = tile_group.shape[0] * bq
+
+            def grouped(row, grp, pos):
+                own = jnp.where(grp == grp[:, :1], pos, m2)  # m2: dropped
+                return jnp.full((n_rows, m2), INF, row.dtype).at[
+                    slot[:, None], own].min(row, mode="drop")
+
+            out = ops.minplus_twoside_grouped(
+                grouped(row_s, grp_s, pos_s), dix.sf_closure[li],
+                grouped(row_t, grp_t, pos_t), tile_group, live,
+                force=force)
+            return jnp.where(key < ng, out[jnp.minimum(slot, n_rows - 1)],
+                             INF)
     c = min(8, mbs)                    # widths are padded to mult of 8
 
     def body(i, acc):
@@ -1722,7 +1773,7 @@ def _top_mid_gather(dix: DeviceIndex, row_s, ids_s, row_t, ids_t):
 
 
 def _combine_mid_h(dix: DeviceIndex, row_s, bs, row_t, bt, *,
-                   force=None):
+                   valid=None, force=None):
     """Hierarchical combine (hierarchy_levels=N, DESIGN.md §12-13):
 
       mid = min_{x,y} row_s[x] + OD(x, y) + row_t[y]
@@ -1732,10 +1783,11 @@ def _combine_mid_h(dix: DeviceIndex, row_s, bs, row_t, bt, *,
     legs), or the route crosses every level's boundary and the TOP
     closure answers against both rows lifted level by level (the vb
     leg).  On an accelerator every leg is a fused minplus_twoside
-    contraction: each va leg scatters both rows into their group's
-    closure coordinates and contracts once per real group (_hier_leg),
-    and the vb leg scatters both rows dense against d2, as the dense
-    path does.  On CPU the va legs gather their closure entries, and
+    contraction: each va leg scatters the rows whose sides share a
+    group (and are ``valid``) into tiles of one group each, in that
+    group's closure coordinates, and contracts them all in one grouped
+    call (_hier_leg), and the vb leg scatters both rows dense against
+    d2, as the dense path does.  On CPU the va legs gather their closure entries, and
     the vb leg stays compact and gathers only each side's own
     top-group boundary columns of d2 (_top_mid_gather — bit-identical,
     the scattered row is +inf everywhere else).  The lift state stays
@@ -1751,7 +1803,8 @@ def _combine_mid_h(dix: DeviceIndex, row_s, bs, row_t, bt, *,
         grp_s, pos_s = dix.sf_of[li][ids_s], dix.pos_in_sf[li][ids_s]
         grp_t, pos_t = dix.sf_of[li][ids_t], dix.pos_in_sf[li][ids_t]
         va = jnp.minimum(va, _hier_leg(dix, li, row_s, grp_s, pos_s,
-                                       row_t, grp_t, pos_t, force=force))
+                                       row_t, grp_t, pos_t, valid=valid,
+                                       force=force))
         new_s = _lift_compact(dix, li, row_s, grp_s, pos_s)
         new_t = _lift_compact(dix, li, row_t, grp_t, pos_t)
         # slot 0 is valid-first by construction, so its group IS the
@@ -1872,7 +1925,8 @@ def _combine_mid_h_w(dix: DeviceIndex, row_s, bs, row_t, bt, *,
     return mid, wx, wy
 
 
-def _combine_mid(dix: DeviceIndex, row_s, bs, row_t, bt, *, force=None):
+def _combine_mid(dix: DeviceIndex, row_s, bs, row_t, bt, *, valid=None,
+                 force=None):
     """combine = min_{b1,b2} row_s[b1] + D_super[bs[b1], bt[b2]]
     + row_t[b2] without a [q, mb, mb] intermediate.
 
@@ -1881,10 +1935,13 @@ def _combine_mid(dix: DeviceIndex, row_s, bs, row_t, bt, *, force=None):
     TPU: scatter-min the boundary rows into SUPER coordinates (one
     O(q*mb) scatter each) and run the fused two-sided tropical kernel
     against the resident D_super.  CPU/ref: chunk the b1 axis so the
-    gathered block never exceeds [q, 8, mb].
+    gathered block never exceeds [q, 8, mb].  ``valid`` (bool [q], or
+    None: every row) marks the rows whose answer is used; the
+    hierarchical legs skip the others on an accelerator.
     """
     if len(dix.sf_of):
-        return _combine_mid_h(dix, row_s, bs, row_t, bt, force=force)
+        return _combine_mid_h(dix, row_s, bs, row_t, bt, valid=valid,
+                              force=force)
     with jax.named_scope("top_combine"):
         if ops.use_pallas(force):
             s1 = dix.d_super.shape[0]
@@ -1998,7 +2055,10 @@ def serve_cross(dix: DeviceIndex, s: jax.Array, t: jax.Array, *,
                 with_local: bool, force=None) -> jax.Array:
     """Planner buckets 2/3: endpoints in different DRAs.  with_local
     folds in the intra-fragment distance (same-fragment bucket only,
-    so the cross-fragment program skips that gather entirely)."""
+    so the cross-fragment program skips that gather entirely).  A row
+    with s == t (the planner's filler; a real such pair is same-DRA)
+    runs no same-group leg on an accelerator: its answer is dropped by
+    the planner and set to 0 by serve_step."""
     us, ut = dix.agent_of[s], dix.agent_of[t]
     ds, dt = dix.dist_to_agent[s], dix.dist_to_agent[t]
     fs, ft = dix.frag_of[us], dix.frag_of[ut]
@@ -2006,7 +2066,8 @@ def serve_cross(dix: DeviceIndex, s: jax.Array, t: jax.Array, *,
     row_s = dix.brow[fs, ps]                     # [q, mb]
     row_t = dix.brow[ft, pt]
     mid = _combine_mid(dix, row_s, dix.bnd_super[fs], row_t,
-                       dix.bnd_super[ft], force=force)
+                       dix.bnd_super[ft],
+                       valid=(s != t) & (fs >= 0) & (ft >= 0), force=force)
     if with_local:
         with jax.named_scope("local_fold"):
             mid = jnp.minimum(mid, jnp.where(

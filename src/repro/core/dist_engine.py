@@ -41,7 +41,8 @@ from ..kernels import ops
 from ..obs import trace
 from . import padding, refresh_pipeline, sssp
 from .device_engine import (DeviceIndex, RefreshStats,
-                            build_device_index_with_plan, refresh_index,
+                            build_device_index_with_plan, leg_tiles,
+                            refresh_index,
                             serve_cross, serve_cross_res, serve_cross_w,
                             serve_hub, serve_same_dra, serve_same_dra_w,
                             serve_step, warmup_refresh)
@@ -93,30 +94,40 @@ def _host_maps(dix: DeviceIndex) -> tuple:
             getattr(dix, "host_hub_agent", None), _frag_groups(dix))
 
 
-def _same_group_counts(s, t, maps) -> dict:
-    """{"same_l<li>": number of (s, t) pairs whose two fragments share
-    a real level-li group} — the rows of each level's same-group
-    contraction that can do useful work."""
+def _same_group_counts(s, t, maps) -> list[np.ndarray]:
+    """Per grouping level, the number of (s, t) pairs per real level-li
+    group whose two fragments share it: the rows of each level's
+    same-group contraction that can do useful work ([] on a dense
+    epoch)."""
     _, agent_of, frag_of, *_, groups = maps
     if not groups:                                 # dense epoch
-        return {}
+        return []
     fs, ft = frag_of[agent_of[s]], frag_of[agent_of[t]]
     valid = (fs >= 0) & (ft >= 0)
     fs, ft = np.where(valid, fs, 0), np.where(valid, ft, 0)
-    return {f"same_l{li}": int(np.count_nonzero(
-                valid & (g[fs] == g[ft]) & (g[fs] >= 0)))
-            for li, g in enumerate(groups)}
+    out = []
+    for g in groups:
+        same = valid & (g[fs] == g[ft]) & (g[fs] >= 0)
+        out.append(np.bincount(g[fs][same], minlength=int(g.max()) + 1))
+    return out
 
 
-def _leg_rows(dix: DeviceIndex, padded: int) -> dict:
-    """{"leg_rows": rows the same-group legs stream for a bucket padded
-    to ``padded``}: on an accelerator every level runs one masked
-    contraction per real group over all the bucket's rows, so the sum
-    over grouping levels of (real groups x padded rows); {} on a dense
-    epoch.  Beside ``same_l<li>`` it gives the share of those rows that
-    does useful work."""
-    ng = sum(int(c.shape[0]) - 1 for c in dix.sf_closure)
-    return {"leg_rows": ng * padded} if ng else {}
+def _leg_rows(counts: list) -> dict:
+    """{"leg_rows": rows the same-group legs stream for a bucket whose
+    pairs per real group and level are ``counts``
+    (_same_group_counts)}: on an accelerator each level packs the rows
+    of group g into ceil(n_g / bq) tiles of bq rows (leg_tiles, the
+    device schedule's own rule), so the sum over levels and groups of
+    tiles x bq, never below the pairs counted; {} on a dense epoch.
+    Beside ``same_l<li>`` it gives the share of those rows that does
+    useful work."""
+    if not counts:
+        return {}
+    rows = 0
+    for c in counts:
+        bq, tiles = leg_tiles(c)
+        rows += int(tiles.sum()) * bq
+    return {"leg_rows": rows}
 
 
 class QueryPlanner:
@@ -348,11 +359,13 @@ class QueryPlanner:
                 if idx.size == 0:
                     continue
                 m = _pad_pow2(idx.size)
-                legs = ({**_same_group_counts(s[idx], t[idx],
-                                              self._maps_of(dix)),
-                         **_leg_rows(dix, m)}
-                        if case in self.LEG_CASES and trace.recording()
-                        else {})
+                legs = {}
+                if case in self.LEG_CASES and trace.recording():
+                    counts = _same_group_counts(s[idx], t[idx],
+                                                self._maps_of(dix))
+                    legs = {**{f"same_l{li}": int(c.sum())
+                               for li, c in enumerate(counts)},
+                            **_leg_rows(counts)}
                 with trace.span("planner.bucket", case=case,
                                 n=int(idx.size), padded=m, **legs):
                     sp = np.zeros(m, np.int32)

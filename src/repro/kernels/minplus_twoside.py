@@ -16,6 +16,12 @@ tile pairs (revisiting pattern).  Each invocation reduces its
 [bq, bk1] x [bk1, bk2] x [bq, bk2] triple down to per-lane partial
 minima [bq, 128]; the final cross-lane min happens outside the kernel
 (a trivial [q, 128] -> [q] reduce).
+
+The grouped form (``minplus_twoside_grouped_pallas``) contracts each
+q tile against its own block of a stack of closures: the caller sorts
+rows into tiles of one group each, and the closure block of every tile
+is picked by scalar prefetch, so one call serves every group and a
+tile streams only the closure it needs.
 """
 from __future__ import annotations
 
@@ -24,6 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .minplus import minplus_tile
 
@@ -32,9 +39,12 @@ _LANES = 128
 
 def _twoside_kernel(rows_ref, d_ref, rowt_ref, out_ref):
     """Min-accumulate one (q, y, x) tile triple into lane partials."""
-    yi = pl.program_id(1)
-    xi = pl.program_id(2)
+    _twoside_step(pl.program_id(1), pl.program_id(2), rows_ref, d_ref,
+                  rowt_ref, out_ref)
 
+
+def _twoside_step(yi, xi, rows_ref, d_ref, rowt_ref, out_ref):
+    """_twoside_kernel's body at y block ``yi`` and x block ``xi``."""
     @pl.when((yi == 0) & (xi == 0))
     def _init():
         out_ref[...] = jnp.full_like(out_ref, jnp.inf)
@@ -45,9 +55,11 @@ def _twoside_kernel(rows_ref, d_ref, rowt_ref, out_ref):
     tmp = minplus_tile(rows, d_ref[...],
                        jnp.full((bq, bk2), jnp.inf, rows.dtype))
     tmp = tmp + rowt              # [bq, bk2]
-    # fold the y tile down to its 128 lanes; cross-lane min is done by
-    # the caller so every store here stays (8, 128)-aligned
-    part = jnp.min(tmp.reshape(bq, bk2 // _LANES, _LANES), axis=1)
+    # fold the y tile down to the output's lanes (128, or the whole
+    # tile where it is narrower); cross-lane min is done by the caller
+    # so every store here stays (8, 128)-aligned
+    lanes = out_ref.shape[1]
+    part = jnp.min(tmp.reshape(bq, bk2 // lanes, lanes), axis=1)
     out_ref[...] = jnp.minimum(out_ref[...], part)
 
 
@@ -88,6 +100,99 @@ def minplus_twoside_pallas(rows: jax.Array, d: jax.Array,
         interpret=interpret,
     )(rows_p, d_p, rowt_p)
     return jnp.min(part, axis=1)[:q]
+
+
+#: the grouped contraction's tiles: q tiles of GROUPED_BQ rows, closure
+#: blocks of GROUPED_BK1 x GROUPED_BK2 (clipped to the closure width).
+#: Small q tiles waste few rows on each group's last tile; large closure
+#: blocks keep the grid's per-step overhead, which dead tiles pay too,
+#: small beside a step's work.  Chosen by a sweep on a v5e at m2 =
+#: 1,024, 2,048 and 4,096, where it was within noise of the best
+#: everywhere (PERF.md §6).
+GROUPED_BQ, GROUPED_BK1, GROUPED_BK2 = 16, 256, 2048
+
+
+def _grouped_kernel(tile_group_ref, live_ref, rows_ref, d_ref, rowt_ref,
+                    out_ref):
+    """_twoside_kernel on the live q tiles; a tile at or past the live
+    count computes nothing (its blocks repeat the last live tile's, so
+    it starts no DMA either)."""
+    del tile_group_ref
+    yi, xi = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _live():
+        _twoside_step(yi, xi, rows_ref, d_ref, rowt_ref, out_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("bq", "bk1", "bk2",
+                                             "interpret"))
+def minplus_twoside_grouped_pallas(rows: jax.Array, d: jax.Array,
+                                   rowt: jax.Array, tile_group: jax.Array,
+                                   live: jax.Array, *,
+                                   bq: int = GROUPED_BQ,
+                                   bk1: int = GROUPED_BK1,
+                                   bk2: int = GROUPED_BK2,
+                                   interpret: bool = False) -> jax.Array:
+    """out[r] = min_{x,y} rows[r,x] + d[tile_group[r // bq], x, y]
+    + rowt[r,y] for r < live * bq, +inf past it.
+
+    Shapes: rows, rowt [T * bq, m2]; d [G, m2, m2]; tile_group int32
+    [T] (values in [0, G)); live int32 scalar, the live tiles, which
+    come first -> out [T * bq].  bk1, bk2 are clipped to m2 and must
+    divide it.  The grid is (T, y blocks, x blocks); tile_group and
+    live are prefetched into scalar memory, where the closure's index
+    map reads the tile's block of d, so d is never copied or padded.
+    """
+    r, k1 = rows.shape
+    _, k1b, k2 = d.shape
+    assert r % bq == 0 and rowt.shape == (r, k2) and k1 == k1b, (
+        rows.shape, d.shape, rowt.shape, bq)
+    bk1, bk2 = min(bk1, k1), min(bk2, k2)
+    assert k1 % bk1 == 0 and k2 % bk2 == 0, (k1, k2, bk1, bk2)
+    lanes = min(_LANES, bk2)
+    ny, nx = k2 // bk2, k1 // bk1
+
+    def block(qi, yi, xi, nl):
+        """A live step's own (q, y, x) block; a dead step repeats the
+        last step of the last live tile."""
+        dead = qi >= nl[0]
+        return (jnp.where(dead, jnp.maximum(nl[0] - 1, 0), qi),
+                jnp.where(dead, ny - 1, yi), jnp.where(dead, nx - 1, xi))
+
+    def rows_map(qi, yi, xi, tg, nl):
+        q, _, x = block(qi, yi, xi, nl)
+        return q, x
+
+    def d_map(qi, yi, xi, tg, nl):
+        q, y, x = block(qi, yi, xi, nl)
+        return tg[q], x, y
+
+    def rowt_map(qi, yi, xi, tg, nl):
+        q, y, _ = block(qi, yi, xi, nl)
+        return q, y
+
+    def out_map(qi, yi, xi, tg, nl):
+        return block(qi, yi, xi, nl)[0], 0
+
+    part = pl.pallas_call(
+        _grouped_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(r // bq, ny, nx),
+            in_specs=[
+                pl.BlockSpec((bq, bk1), rows_map),
+                pl.BlockSpec((pl.Squeezed(), bk1, bk2), d_map),
+                pl.BlockSpec((bq, bk2), rowt_map),
+            ],
+            out_specs=pl.BlockSpec((bq, lanes), out_map),
+        ),
+        out_shape=jax.ShapeDtypeStruct((r, lanes), rows.dtype),
+        interpret=interpret,
+    )(tile_group.astype(jnp.int32), jnp.reshape(live, (1,)).astype(
+        jnp.int32), rows, d, rowt)
+    live_row = jnp.arange(r, dtype=jnp.int32) < live * bq
+    return jnp.where(live_row, jnp.min(part, axis=1), jnp.inf)
 
 
 def _twoside_argmin_kernel(rows_ref, d_ref, rowt_ref, out_ref, wit_ref,

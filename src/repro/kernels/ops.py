@@ -53,6 +53,27 @@ def minplus_twoside(rows: jax.Array, d: jax.Array, rowt: jax.Array, *,
     return _ref.minplus_twoside_ref(rows, d, rowt)
 
 
+#: rows of one q tile of the grouped contraction
+GROUPED_TILE_ROWS = _ts.GROUPED_BQ
+
+
+def minplus_twoside_grouped(rows: jax.Array, d: jax.Array,
+                            rowt: jax.Array, tile_group: jax.Array,
+                            live: jax.Array, *, force: Force = None
+                            ) -> jax.Array:
+    """Grouped two-sided contraction: out[r] = min_{x,y} rows[r,x]
+    + d[tile_group[r // bq], x, y] + rowt[r,y] for the rows of the
+    first ``live`` q tiles of bq = GROUPED_TILE_ROWS rows, +inf past
+    them — one call for every closure of the stack d [G, m2, m2], each
+    tile streaming only its own."""
+    pallas, interp = _use_pallas(force)
+    if pallas:
+        return _ts.minplus_twoside_grouped_pallas(
+            rows, d, rowt, tile_group, live, interpret=interp)
+    return _ref.minplus_twoside_grouped_ref(rows, d, rowt, tile_group,
+                                            live, bq=GROUPED_TILE_ROWS)
+
+
 def label_merge(labs: jax.Array, labt: jax.Array, *, bq: int = 128,
                 bj: int = 512, force: Force = None) -> jax.Array:
     """Hub-label merge: out[q] = min_j labs[q,j] + labt[q,j] — the

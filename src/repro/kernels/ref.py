@@ -59,6 +59,20 @@ def minplus_twoside_ref(rows: jax.Array, d: jax.Array, rowt: jax.Array,
     return jnp.min(tmp + rowt, axis=1)
 
 
+def minplus_twoside_grouped_ref(rows: jax.Array, d: jax.Array,
+                                rowt: jax.Array, tile_group: jax.Array,
+                                live: jax.Array, *, bq: int) -> jax.Array:
+    """out[r] = min_{x,y} rows[r,x] + d[tile_group[r // bq], x, y]
+    + rowt[r,y] for r < live * bq, +inf past it: one plain twoside per
+    closure of the stack, each row taking its own tile's."""
+    grp = jnp.repeat(tile_group, bq)
+    out = jnp.full((rows.shape[0],), jnp.inf, rows.dtype)
+    for g in range(d.shape[0]):
+        out = jnp.where(grp == g, minplus_twoside_ref(rows, d[g], rowt),
+                        out)
+    return jnp.where(jnp.arange(rows.shape[0]) < live * bq, out, jnp.inf)
+
+
 def minplus_twoside_argmin_ref(rows: jax.Array, d: jax.Array,
                                rowt: jax.Array, *, chunk: int = 16
                                ) -> tuple[jax.Array, jax.Array, jax.Array]:

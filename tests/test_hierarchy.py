@@ -24,7 +24,9 @@ import numpy as np
 import pytest
 
 from repro.core import dijkstra, hierarchy
-from repro.core.device_engine import (_hier_leg, _lift_compact,
+from repro.core import dist_engine
+from repro.core.device_engine import (_hier_leg, _leg_key,
+                                      _leg_schedule, _lift_compact,
                                       build_device_index,
                                       build_device_index_with_plan,
                                       index_fields_equal,
@@ -137,11 +139,15 @@ def test_hier_distances_equal_dense(built):
 
 def test_hier_pallas_layout_parity(built):
     """The TPU layout (Pallas kernels in interpret mode) of the
-    hierarchical combine matches the jnp reference layout exactly."""
+    hierarchical combine matches the jnp reference layout exactly, on
+    random pairs and planner filler rows."""
     g, _ix, _dense, (dix2, _p2) = built
     rng = np.random.default_rng(3)
-    s = jnp.asarray(rng.integers(0, g.n, 64), jnp.int32)
-    t = jnp.asarray(rng.integers(0, g.n, 64), jnp.int32)
+    # ending in planner filler rows (s = t = 0)
+    s = jnp.asarray(np.r_[rng.integers(0, g.n, 64), np.zeros(8)],
+                    jnp.int32)
+    t = jnp.asarray(np.r_[rng.integers(0, g.n, 64), np.zeros(8)],
+                    jnp.int32)
     np.testing.assert_array_equal(
         np.asarray(serve_step(dix2, s, t)),
         np.asarray(serve_step(dix2, s, t, force="pallas")))
@@ -226,7 +232,11 @@ def _leg_inputs(dix, s, t):
 def test_hier_leg_contraction_equals_gather(deep):
     """_hier_leg's contraction layout (Pallas, interpret mode) is
     bit-equal to its gather layout at every level, on sides in the
-    same group, in different groups, and sentinel-only."""
+    same group, in different groups, and sentinel-only.  Planner filler
+    rows (s = t = 0, not ``valid``) are no live rows: their keys are
+    the sentinel, they read +inf, and the rows the schedule streams are
+    the tile rule's count over the real pairs alone, the planner's
+    ``leg_rows``."""
     g, dix = deep
     rng = np.random.default_rng(11)
     frag = np.asarray(dix.frag_of)[np.asarray(dix.agent_of)]
@@ -238,6 +248,14 @@ def test_hier_leg_contraction_equals_gather(deep):
     bs = np.asarray(dix.bnd_super)
     padded = np.nonzero((bs == dix.sf_of[0].shape[0] - 1).any(axis=1))[0]
     s[0], t[0] = rng.choice(np.nonzero(frag == padded[0])[0], 2)
+    # the host counts the real pairs, and row 0 again (_leg_inputs'
+    # last row); the filler rows come after the real ones
+    counts = dist_engine._same_group_counts(
+        np.r_[s, s[:1]], np.r_[t, t[:1]], dist_engine._host_maps(dix))
+    s = np.r_[s, np.zeros(5, np.int32)]
+    t = np.r_[t, np.zeros(5, np.int32)]
+    real = np.r_[np.arange(s.size) < 96, np.ones(4, bool)]
+    valid = jnp.asarray(real)
     groups_s = _side_groups(dix, frag[s])
     groups_t = _side_groups(dix, frag[t])
     for li, args in enumerate(_leg_inputs(dix, jnp.asarray(s),
@@ -246,12 +264,21 @@ def test_hier_leg_contraction_equals_gather(deep):
         assert same.any() and not same.all(), li
         assert (args[1][0] != args[1][0, 0]).any(), li   # has sentinels
         ref = np.asarray(_hier_leg(dix, li, *args, force="ref"))
-        kern = np.asarray(_hier_leg(dix, li, *args, force="pallas"))
-        np.testing.assert_array_equal(kern, ref)
+        kern = np.asarray(_hier_leg(dix, li, *args, valid=valid,
+                                    force="pallas"))
+        np.testing.assert_array_equal(kern[real], ref[real])
+        assert np.isinf(kern[~real]).all()
         assert np.isinf(ref[-4:-1]).all() and ref[-1] == ref[0]
         assert np.isfinite(ref[0])
         assert np.isinf(ref[:-4][~same]).all()
         assert np.isfinite(ref[:-4][same]).any()
+        ng = dix.sf_closure[li].shape[0] - 1
+        key = _leg_key(args[1], args[4], valid, ng)
+        assert (np.asarray(key)[~real] == ng).all()
+        assert (np.asarray(key) < ng).sum() == counts[li].sum()
+        bq, _, _, live = _leg_schedule(key, ng)
+        assert int(live) * bq == \
+            dist_engine._leg_rows([counts[li]])["leg_rows"]
 
 
 def test_hier_leg_contraction_oracle(deep):

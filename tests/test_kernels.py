@@ -92,6 +92,56 @@ def test_minplus_twoside_all_inf():
         assert np.isinf(got).all() and not np.isnan(got).any()
 
 
+@pytest.mark.parametrize("counts,extra", [
+    ((3, 21, 9), 1),        # uneven group sizes, one dead tile
+    ((5, 0, 7), 1),         # an empty group
+    ((0, 30, 0), 0),        # every row in one group
+    ((0, 0, 0), 2),         # no live tile at all: all +inf
+    ((16, 32, 16), 0),      # n_g an exact multiple of bq
+    ((4, 9, 2), 3),         # finite rows past the live count
+])
+def test_minplus_twoside_grouped(counts, extra):
+    """The grouped contraction (kernel in interpret mode, at the
+    default blocks and at 128 x 128 blocks, and the jnp reference) vs a
+    direct NumPy cube per row: live tiles answer against their own
+    group's closure, padding rows of a live tile and every row past the
+    live count read +inf, whatever those rows hold."""
+    from repro.kernels import minplus_twoside as ts
+
+    bq, m2, groups = ops.GROUPED_TILE_ROWS, 256, len(counts)
+    rng = np.random.default_rng(sum(counts) * 31 + extra)
+    tiles = [-(-n // bq) for n in counts]
+    live = sum(tiles)
+    tile_group = np.repeat(np.arange(groups), tiles).astype(np.int32)
+    tile_group = np.concatenate([tile_group, np.full(extra, groups - 1,
+                                                     np.int32)])
+    n_rows = (live + extra) * bq
+    d, rows, rowt = (np.array(_rand(shape, rng, inf_frac=0.3))
+                     for shape in ((groups + 1, m2, m2), (n_rows, m2),
+                                   (n_rows, m2)))
+    filled = np.zeros(n_rows, bool)
+    start = 0
+    for n, k in zip(counts, tiles):
+        filled[start:start + n] = True
+        start += k * bq
+    filled[live * bq:] = True          # dead tiles hold finite rows too
+    rows[~filled] = np.inf
+    rowt[~filled] = np.inf
+    want = np.full(n_rows, np.inf, np.float32)
+    for r in range(live * bq):
+        g = tile_group[r // bq]
+        want[r] = np.min(rows[r][:, None] + d[g] + rowt[r][None, :])
+    args = [jnp.asarray(x) for x in (rows, d, rowt, tile_group)]
+    nl = jnp.int32(live)
+    for force in ("ref", "pallas"):
+        got = ops.minplus_twoside_grouped(*args, nl, force=force)
+        np.testing.assert_array_equal(np.asarray(got), want)
+    got = ts.minplus_twoside_grouped_pallas(*args, nl, bk1=128, bk2=128,
+                                            interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert live == 0 or np.isfinite(want).any()
+
+
 @pytest.mark.parametrize("force", ["ref", "pallas"])
 def test_fw_all_inf_padding_blocks(force):
     """The refresh path's pow2 padding (`_fw_bucket(pad_pow2=True)`)

@@ -19,6 +19,7 @@ from repro.core import dist_engine
 from repro.core.dist_engine import QueryPlanner, pad_pow2
 from repro.core.graph import road_like
 from repro.core.supergraph import build_index
+from repro.kernels import ops
 from repro.obs import trace as trace_mod
 
 CASES = QueryPlanner.CASES
@@ -194,19 +195,32 @@ def test_leg_counts_cost_nothing_unrecorded(world, monkeypatch):
 
 def test_leg_buckets_carry_leg_rows(world):
     """Each bucket whose program runs the same-group legs is tagged
-    ``leg_rows``: the real groups of every grouping level times the
-    bucket's padded rows, the rows its masked contractions stream."""
+    ``leg_rows``: the rows its grouped contraction streams, each real
+    group's pairs rounded up to whole q tiles of the tile rule's rows,
+    summed over groups (the world has one grouping level), never below
+    the pairs that can win."""
     _, dix, planner, pairs = world
-    events = _spans_of(planner.query, pairs[:, 0], pairs[:, 1])
-    groups = sum(np.asarray(c).shape[0] - 1 for c in dix.sf_closure)
+    s, t = pairs[:, 0], pairs[:, 1]
+    events = _spans_of(planner.query, s, t)
+    plan = planner.plan(s, t)
+    grp = _level0_group_of_frag(dix)
+    frag = np.asarray(dix.frag_of)[np.asarray(dix.agent_of)]
+    bq = ops.GROUPED_TILE_ROWS
     buckets = [e["args"] for e in events if e["name"] == "planner.bucket"]
     assert {b["case"] for b in buckets} >= set(QueryPlanner.LEG_CASES)
     for b in buckets:
-        if b["case"] in QueryPlanner.LEG_CASES:
-            assert b["leg_rows"] == groups * b["padded"]
-            assert b["leg_rows"] >= b["same_l0"]
-        else:
+        if b["case"] not in QueryPlanner.LEG_CASES:
             assert "leg_rows" not in b
+            continue
+        idx = plan[b["case"]]
+        fs, ft = frag[s[idx]], frag[t[idx]]
+        ok = (fs >= 0) & (ft >= 0)
+        gs = np.where(ok, grp[np.where(ok, fs, 0)], -1)
+        gt = np.where(ok, grp[np.where(ok, ft, 0)], -1)
+        n_g = [int(np.count_nonzero((gs == g) & (gt == g)))
+               for g in range(np.asarray(dix.sf_closure[0]).shape[0] - 1)]
+        assert b["leg_rows"] == sum(-(-n // bq) * bq for n in n_g)
+        assert b["leg_rows"] >= b["same_l0"]
 
 
 def test_leg_rows_cost_nothing_unrecorded(world, monkeypatch):

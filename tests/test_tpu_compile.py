@@ -107,6 +107,24 @@ def test_minplus_twoside_compiles(one_chip, kernel):
     _compile(kernel, one_chip, (BATCH, w), (w, w), (BATCH, w))
 
 
+@pytest.mark.parametrize("groups,m2", [(6, 1024), (10, 2048), (3, 4096)])
+def test_minplus_twoside_grouped_compiles(one_chip, groups, m2):
+    """The grouped same-group leg contraction at every closure width
+    the cells run, over the most q tiles a 1,024-row bucket can take:
+    the closure stack is read whole, each tile's block chosen by
+    scalar prefetch."""
+    tiles = BATCH // ts.GROUPED_BQ + groups
+    rows = (tiles * ts.GROUPED_BQ, m2)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in ((rows, jnp.float32),
+                          ((groups + 1, m2, m2), jnp.float32),
+                          (rows, jnp.float32), ((tiles,), jnp.int32),
+                          ((), jnp.int32))]
+    text = ts.minplus_twoside_grouped_pallas.lower(*args).compile(
+    ).as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
 def test_label_merge_compiles(one_chip):
     """The hub-label merge over full-width label rows."""
     w = S_TOP + 1
@@ -115,8 +133,8 @@ def test_label_merge_compiles(one_chip):
 
 def _leg_text(sharding, groups, width, m2, monkeypatch):
     """The compiled text of the same-group leg in its kernel layout for
-    a 512-row bucket: both sides scattered into m2 group coordinates,
-    one twoside per real group."""
+    a 512-row bucket: both sides scattered into tiles of one group
+    each, in m2 group coordinates, one grouped twoside for the level."""
     monkeypatch.setattr(ops, "_use_pallas", lambda force: (True, False))
 
     def leg(closure, row_s, grp_s, pos_s, row_t, grp_t, pos_t):
@@ -133,16 +151,24 @@ def _leg_text(sharding, groups, width, m2, monkeypatch):
                               *side).compile().as_text()
 
 
+def _assert_one_grouped_call(text, m2):
+    """One grouped contraction for the level, and no [q, m2] copy of a
+    side (the masked per-group layout made one per group)."""
+    assert text.count("tpu_custom_call") == 1
+    assert "minplus_twoside_grouped_pallas" in text
+    assert f"f32[512,{m2}]" not in text
+
+
 @pytest.mark.parametrize("groups,width", [(6, 56),      # level 1
                                           (4, 432)])    # level 2
 def test_same_group_leg_compiles(one_chip, groups, width, monkeypatch):
     """road64k's legs: m2 = 1024, at each grouping level's row width."""
     text = _leg_text(one_chip, groups, width, 1024, monkeypatch)
-    assert text.count("tpu_custom_call") >= groups
+    _assert_one_grouped_call(text, 1024)
 
 
 def test_same_group_leg_compiles_at_4096(one_chip, monkeypatch):
     """road250k's third grouping level: 3 groups at m2 = 4096, rows
     1,616 wide (the second level's boundary slots)."""
     text = _leg_text(one_chip, 3, 1616, 4096, monkeypatch)
-    assert text.count("tpu_custom_call") >= 3
+    _assert_one_grouped_call(text, 4096)
