@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import argparse
 import os
+import time
 from pathlib import Path
 
 import jax
@@ -50,7 +51,6 @@ from ..core.supergraph import (build_index, index_arrays_equal,
                                reweight_index)
 from ..obs import trace
 from ..perflog import append_records, latest
-from ..runtime import StragglerMonitor
 from .mesh import make_host_mesh
 
 REFRESHED_FIELDS = ("frag_apsp", "frag_next", "brow", "d_super",
@@ -336,23 +336,23 @@ def _paths_loop(engine: EpochedEngine, args) -> list:
     """Serve the path-unwinding workload (planner witness programs +
     host-side unwind) and validate a sample; returns perf records."""
     rng = np.random.default_rng(args.seed + 3)
-    monitor = StragglerMonitor()
+    batch_s = []
     total = 0
     last = None
     for _ in range(args.batches):
         s = rng.integers(0, engine.g.n, args.batch_size).astype(np.int32)
         t = rng.integers(0, engine.g.n, args.batch_size).astype(np.int32)
-        monitor.start()
+        t0 = time.perf_counter()
         dist, paths = engine.query_path(s, t)
-        monitor.stop()
+        batch_s.append(time.perf_counter() - t0)
         total += args.batch_size
         last = (s, t, dist, paths)
-    summ = monitor.summary()
-    per_p = summ["median_s"] / args.batch_size
-    pps = args.batch_size / summ["median_s"]
+    median_s = float(np.median(batch_s))
+    per_p = median_s / args.batch_size
+    pps = args.batch_size / median_s
     hops = [len(p) - 1 for p in last[3] if p is not None]
     print(f"paths: {total} unwound; median batch "
-          f"{summ['median_s'] * 1e3:.2f}ms -> {per_p * 1e6:.2f}us/path "
+          f"{median_s * 1e3:.2f}ms -> {per_p * 1e6:.2f}us/path "
           f"({pps:,.0f} paths/s, mean {np.mean(hops):.1f} hops)")
     s, t, dist, paths = last
     assert _validate_paths(engine.g, s, t, dist, paths,
@@ -362,7 +362,7 @@ def _paths_loop(engine: EpochedEngine, args) -> list:
         "graph": _label(args),
         "backend": jax.default_backend(),
         "batch_size": args.batch_size,
-        "median_batch_ms": round(summ["median_s"] * 1e3, 3),
+        "median_batch_ms": round(median_s * 1e3, 3),
         "us_per_path": round(per_p * 1e6, 3),
         "paths_per_s": round(pps, 1),
         "mean_hops": round(float(np.mean(hops)), 1) if hops else 0.0,
@@ -797,7 +797,7 @@ def main(argv=None) -> None:
     g = engine.g if engine is not None else g
 
     rng = np.random.default_rng(args.seed + 1)
-    monitor = StragglerMonitor()
+    batch_s = []
     planner = None
     if mode == "sharded":
         mesh = make_host_mesh()
@@ -822,15 +822,15 @@ def main(argv=None) -> None:
     for i in range(args.batches):
         s = jnp.asarray(rng.integers(0, g.n, args.batch_size), jnp.int32)
         t = jnp.asarray(rng.integers(0, g.n, args.batch_size), jnp.int32)
-        monitor.start()
+        t0 = time.perf_counter()
         out = jax.block_until_ready(jnp.asarray(fn(s, t)))
-        monitor.stop()
+        batch_s.append(time.perf_counter() - t0)
         total_q += args.batch_size
         last = (np.asarray(s), np.asarray(t), np.asarray(out))
-    summ = monitor.summary()
-    per_q = summ["median_s"] / args.batch_size
-    qps = args.batch_size / summ["median_s"]
-    print(f"served {total_q} queries; median batch {summ['median_s']*1e3:.2f}ms "
+    median_s = float(np.median(batch_s))
+    per_q = median_s / args.batch_size
+    qps = args.batch_size / median_s
+    print(f"served {total_q} queries; median batch {median_s*1e3:.2f}ms "
           f"-> {per_q*1e6:.2f}us/query ({qps:,.0f} qps)")
     if planner is not None:
         print(f"planner buckets (last batch): {planner.last_counts}")
@@ -840,7 +840,7 @@ def main(argv=None) -> None:
         "mode": mode,
         "backend": jax.default_backend(),
         "batch_size": args.batch_size,
-        "median_batch_ms": round(summ["median_s"] * 1e3, 3),
+        "median_batch_ms": round(median_s * 1e3, 3),
         "us_per_query": round(per_q * 1e6, 3),
         "qps": round(qps, 1),
         **({} if engine is None else _overlay_record(engine)),

@@ -1,8 +1,8 @@
 import os
 import sys
 
-# Tests run against the single default CPU device (the 512-device flag is
-# dryrun.py-only, per the launch design).
+# Tests run against the single default CPU device; tests/test_multidevice.py
+# asks for more host devices in subprocesses of its own.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 try:

@@ -1,10 +1,15 @@
 """QueryPlanner edge cases: degenerate batches, empty buckets, and
-pow2-padding filler hygiene (DESIGN.md §5).
+pow2-padding filler hygiene (DESIGN.md §5), on every index layout.
 
 The planner pads each case bucket to a power of two with (0, 0)
 self-query filler; none of that filler may ever leak into returned
-distances, for any batch composition.
+distances, for any batch composition.  ``world`` runs each case on the
+dense overlay, on one and two grouping levels, and on two grouping
+levels with the Pallas kernels (interpret mode on the CPU), where the
+same-group legs run as grouped contractions with a filler key.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -14,23 +19,45 @@ from repro.core.dist_engine import QueryPlanner, _pad_pow2
 from repro.core.graph import road_like
 from repro.core.supergraph import build_index
 
+#: layout id -> (hierarchy_levels, planner force); None levels is the
+#: dense overlay of a graph under the auto threshold
+LAYOUTS = {"dense": (None, None), "hier2": (2, None), "hier3": (3, None),
+           "hier3-pallas": (3, "pallas")}
+#: the cases every layout reaches; cross_res needs ``world_res``
+WORLD_CASES = tuple(c for c in QueryPlanner.CASES if c != "cross_res")
 
-@pytest.fixture(scope="module")
-def world():
-    g = road_like(1000, seed=41)
-    ix = build_index(g)
-    dix = build_device_index(ix)
-    return g, dix, QueryPlanner(dix)
+
+@functools.lru_cache(maxsize=None)
+def _hier_world(levels: int):
+    """A hierarchical index of ``levels`` levels whose every grouping
+    level has at least two real groups, so the layout cannot quietly
+    collapse to dense."""
+    g = road_like(2500, seed=3)
+    dix = build_device_index(build_index(g), hierarchy_levels=levels)
+    assert len(dix.sf_of) == levels - 1
+    assert all(c.shape[0] - 1 >= 2 for c in dix.sf_closure)
+    return g, dix
+
+
+@pytest.fixture(scope="module", params=list(LAYOUTS))
+def world(request):
+    levels, force = LAYOUTS[request.param]
+    if levels is None:
+        g = road_like(1000, seed=41)
+        dix = build_device_index(build_index(g))
+        assert len(dix.sf_of) == 0
+    else:
+        g, dix = _hier_world(levels)
+    return g, dix, QueryPlanner(dix, force=force)
 
 
 @pytest.fixture(scope="module")
 def world_res():
-    """A hierarchical index with resident pre-lifted rows, so the
-    cross_res bucket is actually reachable (the dense ``world`` index
-    has no residency and its cross_res bucket is provably empty)."""
-    g = road_like(2500, seed=3)
-    ix = build_index(g)
-    dix = build_device_index(ix, hierarchy_levels=3)
+    """A hierarchical index with resident pre-lifted rows in two top
+    groups, so the cross_res bucket is actually reachable (a dense
+    index has no residency and its cross_res bucket is provably
+    empty)."""
+    g, dix = _hier_world(3)
     rf = getattr(dix, "host_res_frag", None)
     if rf is None or np.asarray(dix.res_rows).shape[0] <= 1:
         pytest.skip("no resident rows at this size")
@@ -56,8 +83,9 @@ def _check(g, planner, pairs):
     return got
 
 
-def _pairs_of_case(g, dix, case, n):
+def _pairs_of_case(g, planner, case, n):
     """n query pairs all belonging to one planner case."""
+    dix = planner.dix
     agent_of = np.asarray(dix.agent_of)
     frag_of = np.asarray(dix.frag_of)
     fa = frag_of[agent_of]
@@ -93,42 +121,63 @@ def _pairs_of_case(g, dix, case, n):
     else:  # cross_frag
         valid = np.nonzero(fa >= 0)[0]
         f0 = fa[valid[0]]
-        other = valid[np.argmax(fa[valid] != f0)]
+        other = fa[valid] != f0
         rf = getattr(dix, "host_res_frag", None)
-        if rf is not None:
-            # on a resident index, make sure the pair is NOT hot (it
-            # would dispatch as cross_res, not cross_frag)
-            rf = np.asarray(rf)
-            cold = np.nonzero(rf[fa[valid]] < 0)[0]
-            if cold.size:
-                other = valid[cold[0]]
+        tg = getattr(dix, "host_topgrp_frag", None)
+        if rf is not None and tg is not None:
+            # two resident fragments in different top groups dispatch
+            # as cross_res: keep the second fragment cold or in the
+            # first one's top group
+            rf, tg = np.asarray(rf), np.asarray(tg)
+            other &= (rf[fa[valid]] < 0) | (rf[f0] < 0) \
+                | (tg[fa[valid]] == tg[f0])
+        assert other.any(), "no cross_frag pair"
+        b = valid[np.argmax(other)]
         for i in range(n):
-            out.append((int(valid[0]), int(other)))
+            out.append((int(valid[0]), int(b)))
     assert len(out) == n, f"could not build {case} pairs"
-    return np.asarray(out)
+    out = np.asarray(out)
+    assert planner.plan(out[:, 0], out[:, 1])[case].size == n
+    return out
 
 
-@pytest.mark.parametrize("case", QueryPlanner.CASES)
-def test_batch_of_one(request, case):
-    g, dix, planner = request.getfixturevalue(
-        "world_res" if case == "cross_res" else "world")
-    pairs = _pairs_of_case(g, dix, case, 1)
+def _batch_of_one(g, planner, case):
+    pairs = _pairs_of_case(g, planner, case, 1)
     _check(g, planner, pairs)
     counts = dict(planner.last_counts)
     assert counts[case] == 1
     assert sum(counts.values()) == 1
 
 
-@pytest.mark.parametrize("case", QueryPlanner.CASES)
-def test_single_case_batches(request, case):
+@pytest.mark.parametrize("case", WORLD_CASES)
+def test_batch_of_one(world, case):
+    g, _, planner = world
+    _batch_of_one(g, planner, case)
+
+
+def test_batch_of_one_cross_res(world_res):
+    g, _, planner = world_res
+    _batch_of_one(g, planner, "cross_res")
+
+
+def _single_case_batch(g, planner, case):
     """A batch entirely of one case: the other sub-programs must
     not be dispatched at all (empty-bucket skip)."""
-    g, dix, planner = request.getfixturevalue(
-        "world_res" if case == "cross_res" else "world")
-    pairs = _pairs_of_case(g, dix, case, 13)   # odd size -> pow2 pad
+    pairs = _pairs_of_case(g, planner, case, 13)   # odd size -> pow2 pad
     _check(g, planner, pairs)
     for c, n in planner.last_counts.items():
         assert n == (13 if c == case else 0)
+
+
+@pytest.mark.parametrize("case", WORLD_CASES)
+def test_single_case_batches(world, case):
+    g, _, planner = world
+    _single_case_batch(g, planner, case)
+
+
+def test_single_case_batches_cross_res(world_res):
+    g, _, planner = world_res
+    _single_case_batch(g, planner, "cross_res")
 
 
 def test_empty_batch(world):
