@@ -1573,7 +1573,8 @@ def leg_tiles(counts):
     """The grouped legs' tile rule -> (bq, tiles): a group with
     counts[g] live rows takes tiles[g] = ceil(counts[g] / bq) q tiles
     of bq rows.  The device schedule (_leg_schedule) and the planner's
-    ``leg_rows`` count both use it, on jnp and numpy counts alike."""
+    ``leg_rows`` and ``lift_rows`` counts all use it, on jnp and numpy
+    counts alike."""
     bq = ops.GROUPED_TILE_ROWS
     return bq, -(-counts // bq)
 
@@ -1614,6 +1615,41 @@ def _leg_schedule(key, ng: int):
     return bq, slot, jnp.minimum(tile_group, ng - 1), ends[-1]
 
 
+def _own_pos(grp, pos, m2: int):
+    """Each slot's position in its side's group (slot 0's), or ``m2``
+    (out of range, so a scatter drops it) for a slot of another group:
+    the sentinel slots, since all valid slots of a side share a group."""
+    return jnp.where(grp == grp[:, :1], pos, m2)
+
+
+def _scatter_grouped(row, slot, pos, n_rows: int, m2: int):
+    """The grouped layout of a side: row q min-scattered into row
+    slot[q] of an [n_rows, m2] block (+inf elsewhere) at its slots'
+    group coordinates ``pos``; a keyless row (slot n_rows) and a slot
+    at pos m2 are dropped."""
+    return jnp.full((n_rows, m2), INF, row.dtype).at[
+        slot[:, None], pos].min(row, mode="drop")
+
+
+def _grouped_lift(row, pos, key, stack, *, force=None):
+    """out[q, j] = min_b row[q, b] + stack[key[q], pos[q, b], j] as one
+    grouped one-sided (min,+) product: _leg_schedule packs the rows
+    into q tiles of one key each, each row is scattered into its tile
+    in m2 coordinates (_scatter_grouped), every tile streams only its
+    own block of the stack [G + 1, m2, width], and each row reads its
+    slot back.  A keyless row (key G, the stack's sentinel block) reads
+    +inf.  Exact: a min is order-free and every sum is an
+    integer-valued f32 below 2**24, so this is bit-equal to gathering
+    the stack's rows slot by slot."""
+    ng, m2 = stack.shape[0] - 1, stack.shape[1]
+    bq, slot, tile_group, live = _leg_schedule(key, ng)
+    n_rows = tile_group.shape[0] * bq
+    out = ops.minplus_grouped(_scatter_grouped(row, slot, pos, n_rows, m2),
+                              stack, tile_group, live, force=force)
+    return jnp.where((key < ng)[:, None],
+                     out[jnp.minimum(slot, n_rows - 1)], INF)
+
+
 def _hier_leg(dix: DeviceIndex, li: int, row_s, grp_s, pos_s,
               row_t, grp_t, pos_t, *, valid=None, force=None):
     """Same-group leg at grouping level ``li``: min over slot pairs
@@ -1650,9 +1686,8 @@ def _hier_leg(dix: DeviceIndex, li: int, row_s, grp_s, pos_s,
             n_rows = tile_group.shape[0] * bq
 
             def grouped(row, grp, pos):
-                own = jnp.where(grp == grp[:, :1], pos, m2)  # m2: dropped
-                return jnp.full((n_rows, m2), INF, row.dtype).at[
-                    slot[:, None], own].min(row, mode="drop")
+                return _scatter_grouped(row, slot, _own_pos(grp, pos, m2),
+                                        n_rows, m2)
 
             out = ops.minplus_twoside_grouped(
                 grouped(row_s, grp_s, pos_s), dix.sf_closure[li],
@@ -1679,15 +1714,32 @@ def _hier_leg(dix: DeviceIndex, li: int, row_s, grp_s, pos_s,
         return jnp.min(tmp + row_t, axis=1)
 
 
-def _lift_compact(dix: DeviceIndex, li: int, row, grp, pos):
+def _lift_compact(dix: DeviceIndex, li: int, row, grp, pos, *,
+                  valid=None, force=None):
     """Lift a compact boundary row one level: out[q, j] = min_b
     row[q, b] + l2row[li][grp_b, pos_b, j].  All valid slots of one
     side share one group per level (groups nest), so the output stays
     COMPACT — its next-level ids are that group's bnd2_sid row, read
     by the caller — instead of scattering to a dense [q, S_{l+1}+1]
-    row at every level.  Chunked so the gathered block stays
-    [q, 8, mb']."""
+    row at every level.
+
+    Accelerator: the lift is a one-sided (min,+) product of the row,
+    in its group's m2 coordinates, against that group's l2row block,
+    one grouped product for the level (_grouped_lift).  Each row is
+    keyed by its side's group, slot 0's, or by the sentinel ``ng``
+    where the side is sentinel-only or ``valid`` (None: every row) is
+    False; its sentinel slots are dropped.  A keyless row reads +inf.
+    CPU/ref: the gather, chunked so the gathered block stays
+    [q, 8, mb'], over every row (``valid`` unused)."""
     q, mb = row.shape
+    if ops.use_pallas(force):
+        ng, m2 = dix.l2row[li].shape[:2]
+        ng -= 1                                  # last group: sentinel
+        key = grp[:, 0] if valid is None else jnp.where(valid, grp[:, 0],
+                                                        ng)
+        with jax.named_scope(f"lift_l{li}"):
+            return _grouped_lift(row, _own_pos(grp, pos, m2), key,
+                                 dix.l2row[li], force=force)
     c = min(8, mb)
     mbn = dix.l2row[li].shape[2]
 
@@ -1786,11 +1838,14 @@ def _combine_mid_h(dix: DeviceIndex, row_s, bs, row_t, bt, *,
     contraction: each va leg scatters the rows whose sides share a
     group (and are ``valid``) into tiles of one group each, in that
     group's closure coordinates, and contracts them all in one grouped
-    call (_hier_leg), and the vb leg scatters both rows dense against
-    d2, as the dense path does.  On CPU the va legs gather their closure entries, and
-    the vb leg stays compact and gathers only each side's own
-    top-group boundary columns of d2 (_top_mid_gather — bit-identical,
-    the scattered row is +inf everywhere else).  The lift state stays
+    call (_hier_leg), each level's lift is one grouped one-sided
+    product per side against the groups' l2row blocks (_lift_compact;
+    rows not ``valid`` are skipped there too), and the vb leg scatters
+    both rows dense against d2, as the dense path does.  On CPU the va
+    legs and the lifts gather their closure entries, and the vb leg
+    stays compact and gathers only each side's own top-group boundary
+    columns of d2 (_top_mid_gather — bit-identical, the scattered row
+    is +inf everywhere else).  The lift state stays
     compact ([q, width] + ids) until the top; one grouping level
     reproduces the two-level combine bit-for-bit (min re-association
     is exact in f32).
@@ -1805,8 +1860,10 @@ def _combine_mid_h(dix: DeviceIndex, row_s, bs, row_t, bt, *,
         va = jnp.minimum(va, _hier_leg(dix, li, row_s, grp_s, pos_s,
                                        row_t, grp_t, pos_t, valid=valid,
                                        force=force))
-        new_s = _lift_compact(dix, li, row_s, grp_s, pos_s)
-        new_t = _lift_compact(dix, li, row_t, grp_t, pos_t)
+        new_s = _lift_compact(dix, li, row_s, grp_s, pos_s, valid=valid,
+                              force=force)
+        new_t = _lift_compact(dix, li, row_t, grp_t, pos_t, valid=valid,
+                              force=force)
         # slot 0 is valid-first by construction, so its group IS the
         # side's group (sentinel-only rows land on the sentinel group,
         # whose bnd2_sid row is all-sentinel and whose rows are +inf)
@@ -1894,8 +1951,8 @@ def _combine_mid_h_w(dix: DeviceIndex, row_s, bs, row_t, bt, *,
         vas.append(va)
         legx.append(xa)
         legy.append(ya)
-        row_s = _lift_compact(dix, li, row_s, grp_s, pos_s)
-        row_t = _lift_compact(dix, li, row_t, grp_t, pos_t)
+        row_s = _lift_compact(dix, li, row_s, grp_s, pos_s, force=force)
+        row_t = _lift_compact(dix, li, row_t, grp_t, pos_t, force=force)
         ids_s = dix.bnd2_sid[li][grp_s[:, 0]]
         ids_t = dix.bnd2_sid[li][grp_t[:, 0]]
     vb, wc, wd = ops.minplus_twoside_argmin(
@@ -2103,15 +2160,26 @@ def serve_cross_w(dix: DeviceIndex, s: jax.Array, t: jax.Array, *,
     return d, wit.astype(jnp.int32)
 
 
-def _lift_res(dix: DeviceIndex, row, pos, ridx, cols=None):
+def _lift_res(dix: DeviceIndex, row, pos, ridx, cols=None, *,
+              valid=None, force=None):
     """Resident lift: rs[q, c] = min_b row[q, b] +
     res_rows[ridx, pos_b, c] — the whole per-level lift ladder
-    collapsed into one chunked gather against the pre-composed rows.
+    collapsed into one product against the pre-composed rows.
 
-    With ``cols`` (int32 [q, w]) the output is restricted to those
-    d2 columns per query instead of the full S_top+1 width — the CPU
-    path passes each endpoint's own top-group boundary ids, cutting
-    the gather traffic to match _top_mid_gather's contraction."""
+    Accelerator (no ``cols``): one grouped one-sided (min,+) product
+    (_grouped_lift) keyed by each row's resident group ``ridx``, or by
+    the sentinel R where it is cold or ``valid`` (None: every row) is
+    False, in level-0 group coordinates ``pos``.  Otherwise a chunked
+    gather, over every row; with ``cols`` (int32 [q, w]) the output is
+    restricted to those d2 columns per query instead of the full
+    S_top+1 width — the CPU path passes each endpoint's own top-group
+    boundary ids, cutting the gather traffic to match
+    _top_mid_gather's contraction."""
+    if cols is None and ops.use_pallas(force):
+        R = dix.res_rows.shape[0] - 1
+        key = ridx if valid is None else jnp.where(valid, ridx, R)
+        with jax.named_scope("lift_res"):
+            return _grouped_lift(row, pos, key, dix.res_rows, force=force)
     q, mb = row.shape
     c = min(8, mb)
     stp1 = dix.res_rows.shape[2]
@@ -2164,8 +2232,11 @@ def serve_cross_res(dix: DeviceIndex, s: jax.Array, t: jax.Array, *,
     pos_s = dix.pos_in_sf[0][bs]
     pos_t = dix.pos_in_sf[0][bt]
     if ops.use_pallas(force):
-        rs = _lift_res(dix, row_s, pos_s, dix.res_of_frag[fs])
-        rt = _lift_res(dix, row_t, pos_t, dix.res_of_frag[ft])
+        valid = (s != t) & (fs >= 0) & (ft >= 0)
+        rs = _lift_res(dix, row_s, pos_s, dix.res_of_frag[fs], valid=valid,
+                       force=force)
+        rt = _lift_res(dix, row_t, pos_t, dix.res_of_frag[ft], valid=valid,
+                       force=force)
         mid = ops.minplus_twoside(rs, dix.d2, rt, force=force)
     else:
         ids_s = dix.bnd2_sid[-1][dix.topgrp_of_frag[fs]]

@@ -123,11 +123,39 @@ def _leg_rows(counts: list) -> dict:
     useful work."""
     if not counts:
         return {}
+    return {"leg_rows": _tile_rows(counts)}
+
+
+def _tile_rows(counts: list) -> int:
+    """The rows grouped contractions stream, one per array of
+    ``counts`` (rows per real key): each key's rows in ceil(n / bq)
+    tiles of bq rows (leg_tiles, the device schedule's own rule)."""
     rows = 0
     for c in counts:
         bq, tiles = leg_tiles(c)
         rows += int(tiles.sum()) * bq
-    return {"leg_rows": rows}
+    return rows
+
+
+def _lift_counts(s, t, maps, case: str) -> list[np.ndarray]:
+    """Per grouped lift of a ``case`` bucket's program, the number of
+    its (s, t) pairs per real key: each side at each grouping level,
+    keyed by that side's group (cross_res: each side's one resident
+    lift, keyed by its resident group).  Pairs with a side outside
+    every fragment are keyless, as on the device.  [] on a dense
+    epoch."""
+    _, agent_of, frag_of, res_frag, _, _, groups = maps
+    keys = [res_frag] if case == "cross_res" else groups
+    if not groups or keys[0] is None:
+        return []
+    fs, ft = frag_of[agent_of[s]], frag_of[agent_of[t]]
+    valid = (fs >= 0) & (ft >= 0)
+    out = []
+    for key in keys:
+        for f in (fs, ft):
+            k = key[np.where(valid, f, 0)]
+            out.append(np.bincount(k[valid & (k >= 0)]))
+    return out
 
 
 class QueryPlanner:
@@ -148,6 +176,10 @@ class QueryPlanner:
     #: legs (_combine_mid_h); their planner.bucket spans carry same_l<li>
     #: and leg_rows
     LEG_CASES = ("same_frag", "cross_frag")
+    #: the cases whose distance programs run grouped lifts
+    #: (_lift_compact, _lift_res); their planner.bucket spans carry
+    #: lift_rows
+    LIFT_CASES = ("same_frag", "cross_frag", "cross_res")
 
     def __init__(self, dix: DeviceIndex, *, force=None,
                  paths: bool = False):
@@ -359,15 +391,20 @@ class QueryPlanner:
                 if idx.size == 0:
                     continue
                 m = _pad_pow2(idx.size)
-                legs = {}
+                tags = {}
                 if case in self.LEG_CASES and trace.recording():
                     counts = _same_group_counts(s[idx], t[idx],
                                                 self._maps_of(dix))
-                    legs = {**{f"same_l{li}": int(c.sum())
+                    tags = {**{f"same_l{li}": int(c.sum())
                                for li, c in enumerate(counts)},
                             **_leg_rows(counts)}
+                if case in self.LIFT_CASES and trace.recording():
+                    lifts = _lift_counts(s[idx], t[idx],
+                                         self._maps_of(dix), case)
+                    if lifts:       # the rows the grouped lifts stream
+                        tags["lift_rows"] = _tile_rows(lifts)
                 with trace.span("planner.bucket", case=case,
-                                n=int(idx.size), padded=m, **legs):
+                                n=int(idx.size), padded=m, **tags):
                     sp = np.zeros(m, np.int32)
                     tp = np.zeros(m, np.int32)
                     sp[:idx.size] = s[idx]

@@ -21,7 +21,9 @@ The grouped form (``minplus_twoside_grouped_pallas``) contracts each
 q tile against its own block of a stack of closures: the caller sorts
 rows into tiles of one group each, and the closure block of every tile
 is picked by scalar prefetch, so one call serves every group and a
-tile streams only the closure it needs.
+tile streams only the closure it needs.  ``minplus_grouped_pallas`` is
+its one-sided twin, out[r, j] = min_k a[r, k] + b[group of r, k, j]:
+the boundary lifts, each row against its group's lift block.
 """
 from __future__ import annotations
 
@@ -112,6 +114,19 @@ def minplus_twoside_pallas(rows: jax.Array, d: jax.Array,
 GROUPED_BQ, GROUPED_BK1, GROUPED_BK2 = 16, 256, 2048
 
 
+def _live_block(*sizes: int):
+    """The grouped grids' block rule, over (q tiles, *sizes) steps: a
+    live step's own (q, ...) block; a dead step (its q tile at or past
+    the live count ``nl[0]``) repeats the last step of the last live
+    tile, so it starts no DMA.  -> block(qi, *steps, nl)."""
+    def block(qi, *steps_nl):
+        *steps, nl = steps_nl
+        dead = qi >= nl[0]
+        return (jnp.where(dead, jnp.maximum(nl[0] - 1, 0), qi),
+                *(jnp.where(dead, n - 1, i) for n, i in zip(sizes, steps)))
+    return block
+
+
 def _grouped_kernel(tile_group_ref, live_ref, rows_ref, d_ref, rowt_ref,
                     out_ref):
     """_twoside_kernel on the live q tiles; a tile at or past the live
@@ -152,13 +167,7 @@ def minplus_twoside_grouped_pallas(rows: jax.Array, d: jax.Array,
     assert k1 % bk1 == 0 and k2 % bk2 == 0, (k1, k2, bk1, bk2)
     lanes = min(_LANES, bk2)
     ny, nx = k2 // bk2, k1 // bk1
-
-    def block(qi, yi, xi, nl):
-        """A live step's own (q, y, x) block; a dead step repeats the
-        last step of the last live tile."""
-        dead = qi >= nl[0]
-        return (jnp.where(dead, jnp.maximum(nl[0] - 1, 0), qi),
-                jnp.where(dead, ny - 1, yi), jnp.where(dead, nx - 1, xi))
+    block = _live_block(ny, nx)
 
     def rows_map(qi, yi, xi, tg, nl):
         q, _, x = block(qi, yi, xi, nl)
@@ -193,6 +202,78 @@ def minplus_twoside_grouped_pallas(rows: jax.Array, d: jax.Array,
         jnp.int32), rows, d, rowt)
     live_row = jnp.arange(r, dtype=jnp.int32) < live * bq
     return jnp.where(live_row, jnp.min(part, axis=1), jnp.inf)
+
+
+def _grouped_onesided_kernel(tile_group_ref, live_ref, a_ref, b_ref,
+                             out_ref):
+    """min-accumulate one (q, k) step of the one-sided grouped product
+    into its output block; a dead q tile computes nothing."""
+    del tile_group_ref
+    # read outside pl.when: the CPU interpreter lowers program_id only
+    # at the kernel's top level
+    ki = pl.program_id(1)
+
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _live():
+        @pl.when(ki == 0)
+        def _init():
+            out_ref[...] = jnp.full_like(out_ref, jnp.inf)
+
+        out_ref[...] = minplus_tile(a_ref[...], b_ref[...], out_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("bq", "bk", "interpret"))
+def minplus_grouped_pallas(a: jax.Array, b: jax.Array,
+                           tile_group: jax.Array, live: jax.Array, *,
+                           bq: int = GROUPED_BQ, bk: int = GROUPED_BK1,
+                           interpret: bool = False) -> jax.Array:
+    """out[r, j] = min_k a[r, k] + b[tile_group[r // bq], k, j] for
+    r < live * bq, +inf past it.
+
+    Shapes: a [T * bq, K]; b [G, K, N]; tile_group int32 [T] (values in
+    [0, G)); live int32 scalar, the live tiles, which come first ->
+    out [T * bq, N].  bk is clipped to K and must divide it; the
+    output block is the whole width N, which a block equal to the
+    array's width may be at any N.  The grid is (T, K blocks), K
+    innermost; tile_group and live are prefetched into scalar memory,
+    where b's index map reads the tile's block, so b is never copied
+    or padded.
+    """
+    r, k = a.shape
+    _, kb, n = b.shape
+    bk = min(bk, k)
+    assert r % bq == 0 and k == kb, (a.shape, b.shape, bq)
+    assert k % bk == 0, (k, bk)
+    nk = k // bk
+    block = _live_block(nk)
+
+    def a_map(qi, ki, tg, nl):
+        return block(qi, ki, nl)
+
+    def b_map(qi, ki, tg, nl):
+        q, kk = block(qi, ki, nl)
+        return tg[q], kk, 0
+
+    def out_map(qi, ki, tg, nl):
+        return block(qi, ki, nl)[0], 0
+
+    out = pl.pallas_call(
+        _grouped_onesided_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(r // bq, nk),
+            in_specs=[
+                pl.BlockSpec((bq, bk), a_map),
+                pl.BlockSpec((pl.Squeezed(), bk, n), b_map),
+            ],
+            out_specs=pl.BlockSpec((bq, n), out_map),
+        ),
+        out_shape=jax.ShapeDtypeStruct((r, n), a.dtype),
+        interpret=interpret,
+    )(tile_group.astype(jnp.int32), jnp.reshape(live, (1,)).astype(
+        jnp.int32), a, b)
+    live_row = jnp.arange(r, dtype=jnp.int32) < live * bq
+    return jnp.where(live_row[:, None], out, jnp.inf)
 
 
 def _twoside_argmin_kernel(rows_ref, d_ref, rowt_ref, out_ref, wit_ref,

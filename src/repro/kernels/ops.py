@@ -74,6 +74,21 @@ def minplus_twoside_grouped(rows: jax.Array, d: jax.Array,
                                             live, bq=GROUPED_TILE_ROWS)
 
 
+def minplus_grouped(a: jax.Array, b: jax.Array, tile_group: jax.Array,
+                    live: jax.Array, *, force: Force = None) -> jax.Array:
+    """Grouped one-sided tropical product: out[r, j] = min_k a[r, k]
+    + b[tile_group[r // bq], k, j] for the rows of the first ``live``
+    q tiles of bq = GROUPED_TILE_ROWS rows, +inf past them — one call
+    for every block of the stack b [G, K, N], each tile streaming only
+    its own (the boundary lifts)."""
+    pallas, interp = _use_pallas(force)
+    if pallas:
+        return _ts.minplus_grouped_pallas(a, b, tile_group, live,
+                                          interpret=interp)
+    return _ref.minplus_grouped_ref(a, b, tile_group, live,
+                                    bq=GROUPED_TILE_ROWS)
+
+
 def label_merge(labs: jax.Array, labt: jax.Array, *, bq: int = 128,
                 bj: int = 512, force: Force = None) -> jax.Array:
     """Hub-label merge: out[q] = min_j labs[q,j] + labt[q,j] — the

@@ -73,6 +73,19 @@ def minplus_twoside_grouped_ref(rows: jax.Array, d: jax.Array,
     return jnp.where(jnp.arange(rows.shape[0]) < live * bq, out, jnp.inf)
 
 
+def minplus_grouped_ref(a: jax.Array, b: jax.Array, tile_group: jax.Array,
+                        live: jax.Array, *, bq: int) -> jax.Array:
+    """out[r, j] = min_k a[r, k] + b[tile_group[r // bq], k, j] for
+    r < live * bq, +inf past it: one plain product per block of the
+    stack, each row taking its own tile's."""
+    grp = jnp.repeat(tile_group, bq)[:, None]
+    out = jnp.full((a.shape[0], b.shape[2]), jnp.inf, a.dtype)
+    for g in range(b.shape[0]):
+        out = jnp.where(grp == g, minplus_ref(a, b[g]), out)
+    live_row = jnp.arange(a.shape[0]) < live * bq
+    return jnp.where(live_row[:, None], out, jnp.inf)
+
+
 def minplus_twoside_argmin_ref(rows: jax.Array, d: jax.Array,
                                rowt: jax.Array, *, chunk: int = 16
                                ) -> tuple[jax.Array, jax.Array, jax.Array]:

@@ -18,7 +18,9 @@ Graphs here are small (forced levels=2), so both closures are cheap
 and the dense one is the oracle.
 """
 import dataclasses
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -27,6 +29,7 @@ from repro.core import dijkstra, hierarchy
 from repro.core import dist_engine
 from repro.core.device_engine import (_hier_leg, _leg_key,
                                       _leg_schedule, _lift_compact,
+                                      _lift_res,
                                       build_device_index,
                                       build_device_index_with_plan,
                                       index_fields_equal,
@@ -308,6 +311,93 @@ def test_hier_leg_contraction_oracle(deep):
                           force="pallas")
         for (a, b), d in zip(pairs, np.asarray(got)):
             assert float(d) == dijkstra.pair(g, a, b), (kind, a, b)
+
+
+def _lift_rows(dix, nodes):
+    """A side's compact boundary row and ids for every node, with two
+    rows appended: a sentinel-only side and the first side again with
+    every sentinel slot's value set to 0.  Both hold finite values, so
+    only the sentinel handling keeps the first +inf and the second
+    equal to the first side."""
+    a = dix.agent_of[jnp.asarray(nodes, jnp.int32)]
+    f = dix.frag_of[a]
+    row, ids = dix.brow[f, dix.pos_in_frag[a]], dix.bnd_super[f]
+    sent = dix.sf_of[0].shape[0] - 1
+    ids = jnp.concatenate([ids, jnp.full_like(ids[:1], sent), ids[:1]])
+    row = jnp.concatenate([row, jnp.zeros_like(row[:1]),
+                           jnp.where(ids[:1] == sent, 0.0, row[:1])])
+    return row, ids
+
+
+def _has_loop(jaxpr) -> bool:
+    """Whether a jaxpr runs a loop: a while, or the scan a fori_loop
+    with static bounds traces to."""
+    return re.search(r"\b(while|scan)\[", str(jaxpr)) is not None
+
+
+@pytest.mark.parametrize("which", ["deep", "four"])
+def test_hier_lift_contraction_equals_gather(which, request):
+    """_lift_compact's grouped-product layout (Pallas, interpret mode)
+    is bit-equal to its gather layout at every level, on sides with
+    sentinel slots and on sentinel-only sides (+inf), and its program
+    runs no loop: the gather's chunk loop is gone."""
+    fix = request.getfixturevalue(which)
+    g, dix = (fix[0], fix[1]) if which == "deep" else (fix[0], fix[2])
+    rng = np.random.default_rng(13)
+    frag = np.asarray(dix.frag_of)[np.asarray(dix.agent_of)]
+    bs = np.asarray(dix.bnd_super)
+    padded = np.nonzero((bs == dix.sf_of[0].shape[0] - 1).any(axis=1))[0]
+    # node 0 of the batch lies in a fragment with sentinel slots
+    nodes = np.r_[np.nonzero(frag == padded[0])[0][:1],
+                  rng.choice(np.nonzero(frag >= 0)[0], 80)]
+    row, ids = _lift_rows(dix, nodes)
+    for li in range(len(dix.sf_of)):
+        grp, pos = dix.sf_of[li][ids], dix.pos_in_sf[li][ids]
+        assert (np.asarray(grp)[0] != np.asarray(grp)[0, 0]).any(), li
+        ref = np.asarray(_lift_compact(dix, li, row, grp, pos, force="ref"))
+        kern = np.asarray(_lift_compact(dix, li, row, grp, pos,
+                                        force="pallas"))
+        np.testing.assert_array_equal(kern, ref)
+        assert np.isinf(ref[-2]).all()
+        np.testing.assert_array_equal(ref[-1], ref[0])
+        assert np.isfinite(ref[:-2]).any(axis=1).all(), li
+
+        def loops(force, li=li, row=row, grp=grp, pos=pos):
+            return _has_loop(jax.make_jaxpr(lambda r: _lift_compact(
+                dix, li, r, grp, pos, force=force))(row))
+
+        assert not loops("pallas") and loops("ref")
+        row, ids = jnp.asarray(ref), dix.bnd2_sid[li][grp[:, 0]]
+
+
+def test_res_lift_contraction_equals_gather(deep):
+    """_lift_res's grouped-product layout (Pallas, interpret mode) is
+    bit-equal to its full-width gather on an index with resident rows,
+    on every resident group, on a cold side (the sentinel row R: +inf)
+    and on a side whose sentinel slots hold finite values (both layouts
+    read them at position 0; a served row holds +inf there); its
+    program runs no loop."""
+    g, dix = deep
+    R = dix.res_rows.shape[0] - 1
+    assert R >= 2
+    rng = np.random.default_rng(17)
+    frag = np.asarray(dix.frag_of)[np.asarray(dix.agent_of)]
+    nodes = rng.choice(np.nonzero(frag >= 0)[0], 80)
+    row, ids = _lift_rows(dix, nodes)
+    ridx = dix.res_of_frag[jnp.asarray(np.r_[frag[nodes], frag[nodes[:1]],
+                                              frag[nodes[:1]]])]
+    ridx = ridx.at[1].set(R)                      # a cold side
+    assert np.unique(np.asarray(ridx)).size == R + 1
+    pos = dix.pos_in_sf[0][ids]
+    ref = np.asarray(_lift_res(dix, row, pos, ridx, force="ref"))
+    kern = np.asarray(_lift_res(dix, row, pos, ridx, force="pallas"))
+    np.testing.assert_array_equal(kern, ref)
+    assert np.isinf(ref[1]).all()
+    assert np.isfinite(ref[2:-2]).any(axis=1).all()
+    sent = np.asarray(ids[:-2]) == dix.sf_of[0].shape[0] - 1
+    assert sent.any() and np.isinf(np.asarray(row[:-2])[sent]).all()
+    assert not _has_loop(jax.make_jaxpr(lambda r: _lift_res(
+        dix, r, pos, ridx, force="pallas"))(row))
 
 
 def test_ov_slot_map_matches_dense_table(built):

@@ -142,6 +142,53 @@ def test_minplus_twoside_grouped(counts, extra):
     assert live == 0 or np.isfinite(want).any()
 
 
+@pytest.mark.parametrize("counts,extra,n", [
+    ((3, 21, 9), 1, 200),   # uneven groups, one dead tile, n % 128 != 0
+    ((5, 0, 7), 1, 128),    # an empty group
+    ((0, 30, 0), 0, 72),    # every row in one group, narrower than a lane
+    ((0, 0, 0), 2, 200),    # no live tile at all: all +inf
+    ((16, 32, 16), 0, 256),  # n_g an exact multiple of bq
+    ((4, 9, 2), 3, 328),    # finite rows past the live count
+])
+def test_minplus_grouped(counts, extra, n):
+    """The grouped one-sided product (kernel in interpret mode, at the
+    default blocks and at 128-wide k blocks, and the jnp reference) vs
+    NumPy per row: live tiles multiply against their own group's block
+    of the stack, and padding rows of a live tile and every row past
+    the live count read +inf, whatever those rows hold.  Output widths
+    need not be multiples of 128: the whole width is one block."""
+    from repro.kernels import minplus_twoside as ts
+
+    bq, k, groups = ops.GROUPED_TILE_ROWS, 256, len(counts)
+    rng = np.random.default_rng(sum(counts) * 37 + extra + n)
+    tiles = [-(-c // bq) for c in counts]
+    live = sum(tiles)
+    tile_group = np.repeat(np.arange(groups), tiles).astype(np.int32)
+    tile_group = np.concatenate([tile_group, np.full(extra, groups - 1,
+                                                     np.int32)])
+    n_rows = (live + extra) * bq
+    b = np.array(_rand((groups + 1, k, n), rng, inf_frac=0.3))
+    a = np.array(_rand((n_rows, k), rng, inf_frac=0.3))
+    filled = np.zeros(n_rows, bool)
+    start = 0
+    for c, t in zip(counts, tiles):
+        filled[start:start + c] = True
+        start += t * bq
+    filled[live * bq:] = True          # dead tiles hold finite rows too
+    a[~filled] = np.inf
+    want = np.full((n_rows, n), np.inf, np.float32)
+    for r in range(live * bq):
+        want[r] = np.min(a[r][:, None] + b[tile_group[r // bq]], axis=0)
+    args = [jnp.asarray(x) for x in (a, b, tile_group)]
+    nl = jnp.int32(live)
+    for force in ("ref", "pallas"):
+        got = ops.minplus_grouped(*args, nl, force=force)
+        np.testing.assert_array_equal(np.asarray(got), want)
+    got = ts.minplus_grouped_pallas(*args, nl, bk=128, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert live == 0 or np.isfinite(want).any()
+
+
 @pytest.mark.parametrize("force", ["ref", "pallas"])
 def test_fw_all_inf_padding_blocks(force):
     """The refresh path's pow2 padding (`_fw_bucket(pad_pow2=True)`)

@@ -13,8 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.device_engine import (build_device_index, serve_cross,
-                                      serve_cross_res, serve_hub)
+from repro.core.device_engine import (_leg_schedule, build_device_index,
+                                      serve_cross, serve_cross_res,
+                                      serve_hub)
 from repro.core import dist_engine
 from repro.core.dist_engine import QueryPlanner, pad_pow2
 from repro.core.graph import road_like
@@ -235,6 +236,76 @@ def test_leg_rows_cost_nothing_unrecorded(world, monkeypatch):
     assert calls == []
     _spans_of(planner.query, pairs[:, 0], pairs[:, 1])
     assert len(calls) == sum(1 for c in QueryPlanner.LEG_CASES
+                             if planner.last_counts[c])
+
+
+def _device_lift_rows(dix, case, s, t) -> int:
+    """The rows the device's grouped lifts stream for one padded
+    bucket: each lift's _leg_schedule live tiles x bq, keyed as
+    _lift_compact (every side, every grouping level) and _lift_res (a
+    cross_res bucket's two resident lifts) key them."""
+    s, t = jnp.asarray(s), jnp.asarray(t)
+    fs = dix.frag_of[dix.agent_of[s]]
+    ft = dix.frag_of[dix.agent_of[t]]
+    valid = (s != t) & (fs >= 0) & (ft >= 0)
+    keys = []
+    for f in (fs, ft):
+        if case == "cross_res":
+            R = dix.res_rows.shape[0] - 1
+            keys.append((jnp.where(valid, dix.res_of_frag[f], R), R))
+            continue
+        ids = dix.bnd_super[f]
+        for li in range(len(dix.sf_of)):
+            grp = dix.sf_of[li][ids]
+            ng = dix.l2row[li].shape[0] - 1
+            keys.append((jnp.where(valid, grp[:, 0], ng), ng))
+            ids = dix.bnd2_sid[li][grp[:, 0]]
+    rows = 0
+    for key, ng in keys:
+        bq, _, _, live = _leg_schedule(key, ng)
+        rows += int(live) * bq
+    return rows
+
+
+def test_lift_buckets_carry_lift_rows(world):
+    """Each bucket whose program runs grouped lifts is tagged
+    ``lift_rows``: the device schedule's live tiles x bq summed over
+    its lifts, for the padded bucket the program is given (its s = t
+    filler rows are keyless), never below a tile's worth of rows per
+    lift and real pair; the other buckets carry no such tag."""
+    _, dix, planner, pairs = world
+    # a self-query last, so a same_dra bucket runs too
+    s, t = np.r_[pairs[:, 0], 0], np.r_[pairs[:, 1], 0]
+    events = _spans_of(planner.query, s, t)
+    plan = planner.plan(s, t)
+    buckets = [e["args"] for e in events if e["name"] == "planner.bucket"]
+    cases = {b["case"] for b in buckets}
+    assert cases == set(QueryPlanner.LIFT_CASES) | {"same_dra"}
+    for b in buckets:
+        if b["case"] not in QueryPlanner.LIFT_CASES:
+            assert "lift_rows" not in b
+            continue
+        idx = plan[b["case"]]
+        sp = np.zeros(b["padded"], np.int32)
+        tp = np.zeros(b["padded"], np.int32)
+        sp[:idx.size], tp[:idx.size] = s[idx], t[idx]
+        assert b["lift_rows"] == _device_lift_rows(dix, b["case"], sp, tp)
+        lifts = 2 * (1 if b["case"] == "cross_res" else len(dix.sf_of))
+        assert b["lift_rows"] >= lifts * b["n"]
+
+
+def test_lift_rows_cost_nothing_unrecorded(world, monkeypatch):
+    """``lift_rows`` is counted only while spans are live."""
+    _, _, planner, pairs = world
+    calls = []
+    real = dist_engine._lift_counts
+    monkeypatch.setattr(dist_engine, "_lift_counts",
+                        lambda *a: calls.append(a) or real(*a))
+    assert not trace_mod.recording()
+    planner.query(pairs[:, 0], pairs[:, 1])
+    assert calls == []
+    _spans_of(planner.query, pairs[:, 0], pairs[:, 1])
+    assert len(calls) == sum(1 for c in QueryPlanner.LIFT_CASES
                              if planner.last_counts[c])
 
 

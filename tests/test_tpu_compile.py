@@ -172,3 +172,44 @@ def test_same_group_leg_compiles_at_4096(one_chip, monkeypatch):
     1,616 wide (the second level's boundary slots)."""
     text = _leg_text(one_chip, 3, 1616, 4096, monkeypatch)
     _assert_one_grouped_call(text, 4096)
+
+
+@pytest.mark.parametrize("groups,m2,width", [
+    (6, 1024, 568),        # road64k: a level's lift
+    (6, 1024, S_TOP + 1),  # road64k: the resident rows
+    (10, 2048, 1096),      # road250k: the three levels' lifts
+    (6, 2048, 1616),
+    (3, 4096, 2168)])
+def test_minplus_grouped_compiles(one_chip, groups, m2, width):
+    """The grouped lift product at every block width the cells run,
+    none a multiple of 128 but one, over the most q tiles a 1,024-row
+    bucket can take: the output block is the block's whole width."""
+    tiles = BATCH // ts.GROUPED_BQ + groups
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in (((tiles * ts.GROUPED_BQ, m2), jnp.float32),
+                          ((groups + 1, m2, width), jnp.float32),
+                          ((tiles,), jnp.int32), ((), jnp.int32))]
+    text = ts.minplus_grouped_pallas.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_lift_compiles_without_a_loop(one_chip, monkeypatch):
+    """road250k's level-2 lift in its kernel layout for a 1,024-row
+    bucket: one grouped product against the l2row stack, and no loop
+    left of the gather's chunk loop."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda force: (True, False))
+
+    def lift(l2row, row, grp, pos):
+        dix = types.SimpleNamespace(l2row=(l2row,))
+        return device_engine._lift_compact(dix, 0, row, grp, pos)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lift).lower(
+        arg((4, 4096, 2168)), arg((BATCH, 1616)),
+        arg((BATCH, 1616), jnp.int32), arg((BATCH, 1616), jnp.int32)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "minplus_grouped_pallas" in text
+    assert " while(" not in text
